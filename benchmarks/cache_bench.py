@@ -1,7 +1,8 @@
 """Policy micro-benchmarks across the three implementation tiers:
 Python reference (the paper's timed implementation), vectorised JAX scan, and
-the Pallas kernel (interpret mode on CPU — the TPU number is roofline-derived,
-see roofline_bench)."""
+the Pallas kernel. ``ops.cache_sim`` compiles the kernel natively on a TPU and
+runs the Pallas interpreter elsewhere; every kernel row names the backend it
+ran on, and an interpreter timing says nothing about the chip."""
 from __future__ import annotations
 
 import numpy as np
@@ -64,7 +65,15 @@ def _kernel_kwargs(kind: str, cap: int) -> dict:
     return kw
 
 
-def pallas_interpret(full: bool = False):
+def _kernel_mode() -> str:
+    """How ``ops.cache_sim`` runs the kernel on this backend."""
+    import jax
+
+    backend = jax.default_backend()
+    return f"{backend}/{'native' if backend == 'tpu' else 'interpret'}"
+
+
+def pallas_kernel(full: bool = False):
     from repro.kernels.cache_sim.ops import cache_sim
 
     n, cap, tlen = 512, 64, 2_000  # interpret mode is python-speed: keep small
@@ -76,18 +85,16 @@ def pallas_interpret(full: bool = False):
         # measure() isolates compile_s and times only warmed, blocked calls
         tr = telemetry.measure(
             cache_sim, traces, kind=kind, n_objects=n, capacity=cap,
-            interpret=True, steps=tlen * 2, repeats=1, **kw,
+            steps=tlen * 2, repeats=1, **kw,
         )
-        hits, _, _ = cache_sim(
-            traces, kind=kind, n_objects=n, capacity=cap, interpret=True, **kw
-        )
+        hits, _, _ = cache_sim(traces, kind=kind, n_objects=n, capacity=cap, **kw)
         rows.append(
             (
-                f"cache_pallas_interp/{kind}",
+                f"cache_pallas/{kind}",
                 tr.us_per_step,
                 tr.derived(
                     CHR=f"{float(np.asarray(hits).sum()) / (tlen * 2):.4f}",
-                    note="(correctness tier; TPU perf in roofline)",
+                    kernel_mode=_kernel_mode(),
                 ),
             )
         )
@@ -97,9 +104,9 @@ def pallas_interpret(full: bool = False):
 def kernel_vs_jax(full: bool = False):
     """Kernel-vs-jax steps-per-sec, one row per sketch-admission kind (wlfu
     rides along as the windowed non-sketch control). Both tiers run the same
-    traces; off-TPU the kernel executes in interpret mode, so the jax column
-    is the meaningful CPU throughput and the recorded ratio is the regression
-    trail for when a TPU runner compiles the kernel natively."""
+    traces and must agree on hits. On a TPU the kernel is compiled natively;
+    elsewhere it runs in the Pallas interpreter, so there only the jax column
+    is a throughput of the backend. The row names which of the two it was."""
     from repro.kernels.cache_sim.ops import cache_sim
 
     n, cap = (2_000, 180) if full else (512, 64)
@@ -115,7 +122,7 @@ def kernel_vs_jax(full: bool = False):
         tr_j = telemetry.measure(
             jax_cache.simulate_batch, spec, traces, static=(0,), steps=steps
         )
-        args = dict(kind=kind, n_objects=n, capacity=cap, interpret=True, **kw)
+        args = dict(kind=kind, n_objects=n, capacity=cap, **kw)
         tr_k = telemetry.measure(cache_sim, traces, steps=steps, repeats=1, **args)
 
         hits_j = jax_cache.simulate_batch(spec, traces)
@@ -130,7 +137,7 @@ def kernel_vs_jax(full: bool = False):
                 f"kernel={tr_k.steps_per_s:,.0f} steps/s jax={tr_j.steps_per_s:,.0f} steps/s "
                 f"ratio={tr_k.steps_per_s / tr_j.steps_per_s:.3f} "
                 f"kernel_compile_s={tr_k.compile_s:.3f} jax_compile_s={tr_j.compile_s:.3f} "
-                f"(interpret mode off-TPU)",
+                f"kernel_mode={_kernel_mode()}",
             )
         )
     return rows
@@ -139,6 +146,6 @@ def kernel_vs_jax(full: bool = False):
 ALL = {
     "cache_py": python_reference,
     "cache_jax": jax_batched,
-    "cache_pallas": pallas_interpret,
+    "cache_pallas": pallas_kernel,
     "kernel_vs_jax": kernel_vs_jax,
 }

@@ -243,6 +243,9 @@ def fleet_weak_scaling(full: bool = False):
             proc = subprocess.run(
                 [sys.executable, "-c", script],
                 capture_output=True, text=True, timeout=600,
+                # forced host devices: the worker must never reach for an
+                # accelerator the parent process may already hold
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
             res = json.loads(proc.stdout.strip().splitlines()[-1])
         except Exception as e:  # pragma: no cover - worker diagnostics
@@ -268,7 +271,8 @@ def fleet_weak_scaling(full: bool = False):
                 1e6 / sps,
                 f"steps_per_s={sps:.0f} edges_per_replica={edges} "
                 f"replicas={spd * D} edge_instances={edges * spd * D} "
-                f"{speedup} host_cores={os.cpu_count()}",
+                f"{speedup} host_cores={os.cpu_count()} "
+                f"devices=host-cpu-forced",
             )
         )
     return rows

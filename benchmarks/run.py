@@ -59,6 +59,9 @@ def main() -> None:
                     help="override compare's relative throughput tolerance")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     baseline = None
     if args.compare is not None:
         # load before running: --record may legitimately overwrite the file

@@ -28,7 +28,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.cdn.router import route_device
@@ -79,7 +78,7 @@ def _edge_sharded_fn(topo: Topology, mesh: Mesh):
         served = jax.lax.psum(hits.any(axis=0).astype(jnp.int32), axis) > 0
         return states, hits, served
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         edge_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
@@ -163,13 +162,14 @@ def _edge_sharded_placed_fn(topo: Topology, mesh: Mesh):
         tuple(edge_or_rep(l) for l in range(L)),
         tuple(P() for _ in range(L)),
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(), P()),
         out_specs=out_specs,
-        check_rep=False,  # upper tiers are replicated by construction (the
-        # per-step psum), which the rep checker cannot see through the scan
+        check_vma=False,  # upper tiers are replicated by construction (the
+        # per-step psum), which the varying-axes check cannot see through
+        # the scan
     )
 
     @jax.jit
@@ -245,11 +245,15 @@ def _device_fleet_fn(
 
     # each shard receives its own chunk of global sample ids and synthesizes
     # + simulates those traces entirely on its device
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda ids: jax.vmap(per_sample)(ids),
         mesh=mesh,
         in_specs=(P(axis),),
         out_specs=P(axis),
+        # shards are independent replicas with no collective, so there is
+        # nothing for the varying-axes check to verify; left on, it would
+        # demand a pcast on every engine scan's constant initial carry
+        check_vma=False,
     )
 
     @jax.jit

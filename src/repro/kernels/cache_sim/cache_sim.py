@@ -27,23 +27,30 @@ TPU-native formulation (no gathers/scatters):
 bloom front) over LFU eviction; ``plfua_dyn`` hoists the hot-mask refresh out
 of the inner step exactly like ``jax_cache._chunked_scan`` does: the trace is
 walked in ``refresh``-length chunks with the hot mask frozen, and the
-estimate-all + top-k rank selection runs once per chunk boundary (global-time
-cadence — a partial tail chunk never fires). The rank selection is a double
-stable argsort over the estimate row (PR 7; it replaced the O(N^2) pairwise
-comparison matrix flagged as the roofline-dominating term in BENCH_PR4),
-reproducing ``lax.top_k``'s ordering (estimate desc, ties to the lowest id)
-bit for bit.
+estimate-all + top-k selection runs once per chunk boundary (global-time
+cadence — a partial tail chunk never fires). The top-k is sort-free: two
+bisections (the k-th largest estimate, then the id cut among its ties)
+select exactly ``lax.top_k``'s set (estimate desc, ties to the lowest id).
+
+Layout (what Mosaic accepts for a TPU): every per-id row is a dense
+``(n_pad // 128, 128)`` tile array (id = sublane * 128 + lane, n_pad a
+multiple of 8 * 128), and so are the sketch rows, the bloom bits and the wlfu
+ring; per-sample blocks squeeze the leading sample dim. The trace is a dense
+``(T_pad // 128, 128)`` block too, read one aligned (8, 128) tile per step
+with the id picked by a one-hot sum (a dynamic scalar load from VMEM does not
+lower). Bool state is carried through loops as int32 (:func:`_fori`), the
+argmin is ``min`` + lowest iota at the min, and ``hits`` leaves via SMEM.
 
 PR 7 additions: the ``gdsf`` kind (score row ``L + (freq << GDSF_SHIFT) //
 size`` with the aging credit ``L`` as a scalar carry) and *byte-capacity*
 mode for the base-step family (lru/lfu/plfu/plfua/plfua_dyn/gdsf): per-object
-sizes arrive as a second, grid-shared ``(1, n_pad)`` input (padding lanes are
+sizes arrive as a second, grid-shared per-id input (padding lanes are
 size 1) and one insertion runs a bounded multi-victim eviction loop — at most
 ``max_victims`` masked argmins — mirroring ``jax_cache.step`` decision for
 decision. ``wlfu``/``tinylfu`` under a byte budget are a JAX-scan-only
 combination (``cache_sim_pallas`` raises).
 
-PR 9: the ``arc`` kind. The four ARC lists live as one (1, n_pad) ``lst``
+The ``arc`` kind: the four ARC lists live as one per-id ``lst``
 row (0 = untracked, 1 = T1, 2 = T2, 3 = B1, 4 = B2) plus a ``stamp`` row of
 last-touch times: list sizes are lane-sums over ``lst == L``, each list's LRU
 is a masked argmin over ``stamp``, and the adaptation target ``p`` is a
@@ -61,7 +68,7 @@ lane-sums over a static Python loop — the kernel-shaped spelling of the jax
 tier's one-hot group matmuls, summing over groups to the ungrouped series
 bit for bit. The n_groups=0 program is unchanged.
 
-The only dynamic access is the scalar trace read ``trace_ref[0, t]`` per step.
+The only dynamic access is the aligned trace-tile load per step.
 Every kind in ``repro.core.registry`` is implemented here; differential
 parity against both ``jax_cache.simulate`` and the pure-Python references is
 asserted in tests/test_kernels_cache_sim.py and tests/test_differential.py.
@@ -102,11 +109,48 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def _tile_rows(n: int) -> int:
+    """Rows of the dense (rows, 128) layout that hold ``n`` lanes, rounded to
+    whole (8, 128) tiles."""
+    return _round_up(max(n, 1), 8 * 128) // 128
+
+
+def _dense_iota(rows: int):
+    """(rows, 128) int32 flat index, sublane * 128 + lane."""
+    shape = (rows, 128)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128 + (
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    )
+
+
+def _fori(lo, hi, body, init):
+    """``lax.fori_loop`` that carries bool leaves as int32 (Mosaic cannot
+    carry i1 vectors through a loop); ``body`` sees and returns bools."""
+    leaves, tree = jax.tree.flatten(init)
+    is_bool = [leaf.dtype == jnp.bool_ for leaf in leaves]
+
+    def enc(c):
+        ls = jax.tree.leaves(c)
+        return [l.astype(jnp.int32) if b else l for l, b in zip(ls, is_bool)]
+
+    def dec(ls):
+        return jax.tree.unflatten(
+            tree, [l != 0 if b else l for l, b in zip(ls, is_bool)]
+        )
+
+    return dec(jax.lax.fori_loop(lo, hi, lambda t, c: enc(body(t, dec(c))), enc(init)))
+
+
+def _pick(c, a, b):
+    """``jnp.where`` over bool operands (Mosaic has no select of i1 vectors)."""
+    return (c & a) | (~c & b)
+
+
 def _bucket_rows(iota_u32, salts, width: int):
     """Per-row lowbias32 bucket tables, computed in-kernel.
 
-    ``iota_u32``: (1, n_pad) uint32 id iota. Returns one (1, n_pad) int32
-    table per salt — identical bits to ``sketch.bucket_table`` /
+    ``iota_u32``: per-id uint32 iota. Returns one int32 table of the same
+    shape per salt — identical bits to ``sketch.bucket_table`` /
     ``sketch.bloom_table`` because the arithmetic is uint32-only.
     """
     u = jnp.uint32
@@ -151,37 +195,63 @@ def _bloom_set(bloom, b_iota, bidx):
     return bloom | marks
 
 
-def _refresh_hot(rows, tables, *, n_pad: int, n_objects: int, hot_k: int):
+def _top_k_mask(est, k: int, iota):
+    """Mask of ``lax.top_k(est, k)``'s ids without a sort: estimate desc,
+    ties to the lowest id. Valid estimates are >= 0 and padding lanes hold
+    -1, so they are never picked while k <= the valid count.
+
+    Two bisections of fixed length: v = the k-th largest estimate (the
+    largest v with #(est >= v) >= k), then the id cut m among the ties at v
+    (the least m with #(est == v, id < m) >= k - #(est > v)). The mask is
+    ``est > v | (est == v & id < m)``."""
+    if k == 0:
+        return jnp.zeros(est.shape, jnp.bool_)
+    count = lambda m: jnp.sum(m.astype(jnp.int32))
+
+    def bisect_v(_, lh):  # invariant: #(est >= lo) >= k > #(est >= hi)
+        lo, hi = lh
+        mid = lo + (hi - lo) // 2
+        ok = count(est >= mid) >= k
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
+
+    v, _ = jax.lax.fori_loop(0, 32, bisect_v, (jnp.int32(0), jnp.max(est) + 1))
+    gt = est > v
+    tie = est == v
+    need = k - count(gt)
+
+    def bisect_m(_, lh):  # invariant: #(tie, id < lo) < need <= #(tie, id < hi)
+        lo, hi = lh
+        mid = lo + (hi - lo) // 2
+        ok = count(tie & (iota < mid)) >= need
+        return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
+
+    n_lanes = est.size
+    _, m = jax.lax.fori_loop(
+        0, n_lanes.bit_length() + 1, bisect_m, (jnp.int32(0), jnp.int32(n_lanes))
+    )
+    return gt | (tie & (iota < m))
+
+
+def _refresh_hot(rows, tables, *, width: int, n_objects: int, hot_k: int, iota):
     """plfua_dyn chunk-boundary refresh: hot mask = sketch top-``hot_k``.
 
-    Estimate-all is a one-hot reduction per row (no gather); the top-k is a
-    *double stable argsort* over the estimate row: the first sort orders ids
-    by estimate descending (stable, so ties keep ascending-id order — exactly
-    ``lax.top_k``), the second inverts that permutation into per-id ranks,
-    and ``rank < hot_k`` is the mask. O(N log N) instead of the previous
-    O(N^2) pairwise comparison matrix (the BENCH_PR4 roofline term), with
-    the same bit-exact order as ``jax_cache.refresh_hot``. Padding lanes get
-    estimate -1 so they always sort last. Returns (hot (1, n_pad) bool,
-    halved rows).
+    Estimate-all without a gather: per sketch row, a walk over the ``width``
+    counters writes each counter's value into the ids hashed to it (one
+    lane-pick and one select per counter). The top-k is :func:`_top_k_mask`,
+    the same set as ``jax_cache.refresh_hot``'s ``lax.top_k``. Padding lanes
+    get estimate -1. Returns (hot bool mask, halved rows).
     """
-    w_pad = rows[0].shape[-1]
-    w_iota = jax.lax.broadcasted_iota(jnp.int32, (1, w_pad), 1)
+    w_iota = _dense_iota(rows[0].shape[0])
     est = None
-    for d in range(len(rows)):
-        tbl_col = jnp.transpose(tables[d])  # (n_pad, 1)
-        match = tbl_col == w_iota  # (n_pad, w_pad)
-        est_d = jnp.sum(jnp.where(match, rows[d], 0), axis=1, keepdims=True)
-        est = est_d if est is None else jnp.minimum(est, est_d)
-    valid_col = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0) < n_objects
-    est = jnp.where(valid_col, est, -1)  # (n_pad, 1)
+    for row, tbl in zip(rows, tables):
 
-    est_row = jnp.transpose(est)  # (1, n_pad); valid est >= 0, padding -1
-    # ascending sort of -est = estimate descending; stable keeps ties in
-    # ascending-id order; padding (-est = 1 > any valid -est <= 0) sorts last
-    perm = jnp.argsort(-est_row, axis=-1, stable=True)
-    rank = jnp.argsort(perm, axis=-1, stable=True)  # invert: id -> its rank
-    hot = rank < hot_k  # (1, n_pad) bool
-    return hot, [r >> 1 for r in rows]
+        def put(c, e, row=row, tbl=tbl):
+            return jnp.where(tbl == c, _lane_pick(w_iota == c, row), e)
+
+        est_d = jax.lax.fori_loop(0, width, put, jnp.zeros_like(iota))
+        est = est_d if est is None else jnp.minimum(est, est_d)
+    est = jnp.where(iota < n_objects, est, -1)
+    return _top_k_mask(est, hot_k, iota), [r >> 1 for r in rows]
 
 
 def _cache_sim_kernel(
@@ -205,21 +275,28 @@ def _cache_sim_kernel(
     BYTES = capacity_bytes > 0
     SIZED = BYTES or kind == "gdsf"
     GROUPED = telemetry_window > 0 and n_groups > 0
-    trace_ref = refs[0]  # (1, T) int32 VMEM
+    trace_ref = refs[0]  # (T_pad // 128, 128) int32 VMEM
     i = 1
     if SIZED:
-        sizes_ref = refs[i]  # (1, N_pad) int32 VMEM, grid-shared; padding = 1
+        sizes_ref = refs[i]  # per-id int32 VMEM, grid-shared; padding = 1
         i += 1
     if GROUPED:
-        groups_ref = refs[i]  # (1, N_pad) int32 VMEM, grid-shared; padding = 0
+        groups_ref = refs[i]  # per-id int32 VMEM, grid-shared; padding = 0
         i += 1
-    hits_ref = refs[i]  # (1, 1) int32 VMEM out
-    freq_ref = refs[i + 1]  # (1, N_pad) int32 VMEM out (lru: last-access stamps)
-    cache_ref = refs[i + 2]  # (1, N_pad) int32 VMEM out (0/1 mask)
-    tel_refs = refs[i + 3 :]  # (1, ROWS, n_w_pad) out, iff telemetry_window
+    hits_ref = refs[i]  # (1, 1) int32 SMEM out
+    freq_ref = refs[i + 1]  # per-id int32 VMEM out (lru: last-access stamps)
+    cache_ref = refs[i + 2]  # per-id int32 VMEM out (0/1 mask)
+    tel_refs = refs[i + 3 :]  # (ROWS, n_w_pad) out, iff telemetry_window
 
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
+    iota = _dense_iota(n_pad // 128)
     iota_u32 = iota.astype(jnp.uint32)
+    tile_iota = _dense_iota(8)
+
+    def request(t):
+        """trace[t]: the aligned (8, 128) tile holding t, one-hot picked."""
+        tile = trace_ref[pl.ds(pl.multiple_of((t // 1024) * 8, 8), 8), :]
+        return _lane_pick(tile_iota == t % 1024, tile)
+
     if SIZED:
         sizes_row = sizes_ref[...]
     if GROUPED:
@@ -299,22 +376,22 @@ def _cache_sim_kernel(
 
     sketchy = kind in _SKETCH_KINDS
     if sketchy:
-        w_pad = _round_up(max(sketch_width, 128), 128)
-        w_iota = jax.lax.broadcasted_iota(jnp.int32, (1, w_pad), 1)
+        w_iota = _dense_iota(_tile_rows(sketch_width))
         tables = _bucket_rows(iota_u32, sketch._SALTS, sketch_width)
-        rows0 = [jnp.zeros((1, w_pad), jnp.int32) for _ in sketch._SALTS]
+        rows0 = [jnp.zeros(w_iota.shape, jnp.int32) for _ in sketch._SALTS]
     if kind == "tinylfu" and doorkeeper:
-        b_pad = _round_up(max(doorkeeper, 128), 128)
-        b_iota = jax.lax.broadcasted_iota(jnp.int32, (1, b_pad), 1)
+        b_iota = _dense_iota(_tile_rows(doorkeeper))
         btables = _bucket_rows(iota_u32, sketch._BLOOM_SALTS, doorkeeper)
     if kind == "wlfu":
-        r_pad = _round_up(max(window, 128), 128)
-        r_iota = jax.lax.broadcasted_iota(jnp.int32, (1, r_pad), 1)
+        r_iota = _dense_iota(_tile_rows(window))
 
-    def victim_of(freq, in_cache):
-        scores = jnp.where(in_cache, freq, _I32_MAX)
-        victim = jnp.argmin(scores)  # flat == lane index for (1, n_pad)
-        return iota == victim
+    def victim_of(keyrow, member):
+        """One-hot of the lowest id minimising ``keyrow`` over ``member``:
+        ``min`` then the lowest iota at the min (Mosaic lowers argmin for
+        float32 only). An empty ``member`` gives id 0, as argmin would."""
+        scores = jnp.where(member, keyrow, _I32_MAX)
+        low = jnp.min(scores)
+        return iota == jnp.min(jnp.where(scores == low, iota, _I32_MAX))
 
     # ---------------------------------------------------------------- steps
     def base_step(t, carry, active=None):
@@ -336,7 +413,7 @@ def _cache_sim_kernel(
             j += 2
         if BYTES:
             nbytes = carry[j]
-        x = trace_ref[0, jnp.minimum(t, trace_len - 1)]
+        x = request(jnp.minimum(t, trace_len - 1))
         onehot = iota == x
         hit = jnp.any(onehot & in_cache)
         if SIZED:
@@ -376,7 +453,7 @@ def _cache_sim_kernel(
                     keyrow = jnp.where(v_oh & need, 0, keyrow)
                 return ic, cnt, nb, keyrow, cr
 
-            new_in_cache, new_count, nb, key, cr = jax.lax.fori_loop(
+            new_in_cache, new_count, nb, key, cr = _fori(
                 0,
                 max_victims,
                 evict_body,
@@ -440,7 +517,7 @@ def _cache_sim_kernel(
             )
         if active is not None:
             new_freq = jnp.where(active, new_freq, freq)
-            new_in_cache = jnp.where(active, new_in_cache, in_cache)
+            new_in_cache = _pick(active, new_in_cache, in_cache)
             new_count = jnp.where(active, new_count, count)
             if kind == "gdsf":
                 new_score = jnp.where(active, new_score, score)
@@ -466,7 +543,7 @@ def _cache_sim_kernel(
         if TEL:
             *carry, tel = carry
         freq, in_cache, count, hits, ring, ptr = carry
-        x = trace_ref[0, t]
+        x = request(t)
         onehot = iota == x
         # slide the window *before* the hit test, as the reference does
         ptr_onehot = r_iota == ptr
@@ -503,7 +580,7 @@ def _cache_sim_kernel(
             freq, in_cache, count, hits, rows, seen, bloom = carry
         else:
             freq, in_cache, count, hits, rows, seen = carry
-        x = trace_ref[0, t]
+        x = request(t)
         onehot = iota == x
         idx = [_lane_pick(onehot, tbl) for tbl in tables]
         # sketch first (add, then age), exactly as TinyLFUCache.request does
@@ -577,7 +654,7 @@ def _cache_sim_kernel(
         if TEL:
             *carry, tel = carry
         stamp, in_cache, count, hits, lst, p = carry
-        x = trace_ref[0, t]
+        x = request(t)
         onehot = iota == x
         lx = _lane_pick(onehot, lst)
         hit = (lx == 1) | (lx == 2)
@@ -613,7 +690,7 @@ def _cache_sim_kernel(
         # == p on a B2 hit, or T2 empty) to B1's MRU, else T2's LRU to B2's
         need_evict = (~hit) & (~hard_t1) & (t1n + t2n >= capacity)
         from_t1 = (t1n >= 1) & ((g2 & (t1n == p)) | (t1n > p) | (t2n == 0))
-        victim_oh = jnp.where(hard_t1 | from_t1, list_lru(1), list_lru(2))
+        victim_oh = _pick(hard_t1 | from_t1, list_lru(1), list_lru(2))
         evict = need_evict | hard_t1
         vdst = jnp.where(hard_t1, 0, jnp.where(from_t1, 3, 4))
         lst = jnp.where(victim_oh & evict, vdst, lst)
@@ -640,26 +717,26 @@ def _cache_sim_kernel(
         return stamp, in_cache, count, hits, lst, p
 
     # -------------------------------------------------------------- drivers
-    freq0 = jnp.zeros((1, n_pad), jnp.int32)
-    cache0 = jnp.zeros((1, n_pad), jnp.bool_)
+    freq0 = jnp.zeros(iota.shape, jnp.int32)
+    cache0 = jnp.zeros(iota.shape, jnp.bool_)
     zero = jnp.int32(0)
-    gdsf0 = (jnp.zeros((1, n_pad), jnp.int32), zero) if kind == "gdsf" else ()
+    gdsf0 = (jnp.zeros(iota.shape, jnp.int32), zero) if kind == "gdsf" else ()
     bytes0 = (zero,) if BYTES else ()
     tel0 = (jnp.zeros((ROWS, n_w_pad), jnp.int32),) if TEL else ()
 
     if kind == "wlfu":
-        ring0 = jnp.full((1, r_pad), -1, jnp.int32)
-        carry = jax.lax.fori_loop(
+        ring0 = jnp.full(r_iota.shape, -1, jnp.int32)
+        carry = _fori(
             0, trace_len, wlfu_step, (freq0, cache0, zero, zero, ring0, zero) + tel0
         )
     elif kind == "tinylfu":
         carry = (freq0, cache0, zero, zero, rows0, zero)
         if doorkeeper:
-            carry = carry + (jnp.zeros((1, b_pad), jnp.bool_),)
-        carry = jax.lax.fori_loop(0, trace_len, tinylfu_step, carry + tel0)
+            carry = carry + (jnp.zeros(b_iota.shape, jnp.bool_),)
+        carry = _fori(0, trace_len, tinylfu_step, carry + tel0)
     elif kind == "arc":
-        lst0 = jnp.zeros((1, n_pad), jnp.int32)
-        carry = jax.lax.fori_loop(
+        lst0 = jnp.zeros(iota.shape, jnp.int32)
+        carry = _fori(
             0, trace_len, arc_step, (freq0, cache0, zero, zero, lst0, zero) + tel0
         )
     elif kind == "plfua_dyn":
@@ -677,13 +754,14 @@ def _cache_sim_kernel(
                 t = base + tl
                 return base_step(t, cy, active=t < trace_len)
 
-            carry = jax.lax.fori_loop(0, refresh, step_in_chunk, carry)
+            carry = _fori(0, refresh, step_in_chunk, carry)
             if TEL:
                 *carry, tel = carry
             freq, in_cache, count, hits, rows, hot, *extra = carry
             fire = (c + 1) * refresh <= trace_len
             new_hot, new_rows = _refresh_hot(
-                rows, tables, n_pad=n_pad, n_objects=n_objects, hot_k=hot_size
+                rows, tables, width=sketch_width, n_objects=n_objects,
+                hot_k=hot_size, iota=iota,
             )
             if TEL:
                 # refresh + hot-churn land in the window of the request that
@@ -695,7 +773,7 @@ def _cache_sim_kernel(
                     # the refresh is attributed to the group of the request
                     # that completed the period; churn is membership-split
                     # over the hot-mask diff (the jax tier's churn_g matmul)
-                    gp = _lane_pick(iota == trace_ref[0, pos], groups_row)
+                    gp = _lane_pick(iota == request(pos), groups_row)
                     inc = (m_iota == gp * _TEL_ROWS + 7).astype(jnp.int32) * fire_i
                     diff = hot != new_hot
                     for g in range(n_groups):
@@ -705,19 +783,19 @@ def _cache_sim_kernel(
                 else:
                     churn = jnp.sum((hot != new_hot).astype(jnp.int32))
                     tel = tel + (_row(7) * fire_i + _row(8) * (churn * fire_i)) * won
-            hot = jnp.where(fire, new_hot, hot)
+            hot = _pick(fire, new_hot, hot)
             rows = [jnp.where(fire, nr, r) for nr, r in zip(new_rows, rows)]
             out = (freq, in_cache, count, hits, rows, hot, *extra)
             return out + (tel,) if TEL else out
 
-        carry = jax.lax.fori_loop(
+        carry = _fori(
             0,
             n_chunks,
             chunk,
             (freq0, cache0, zero, zero, rows0, hot0) + bytes0 + tel0,
         )
     else:
-        carry = jax.lax.fori_loop(
+        carry = _fori(
             0,
             trace_len,
             base_step,
@@ -729,7 +807,7 @@ def _cache_sim_kernel(
     freq_ref[...] = freq
     cache_ref[...] = in_cache.astype(jnp.int32)
     if TEL:
-        tel_refs[0][...] = carry[-1][None]
+        tel_refs[0][...] = carry[-1]
 
 
 def cache_sim_pallas(
@@ -749,7 +827,7 @@ def cache_sim_pallas(
     sizes=None,
     n_groups: int = 0,
     groups=None,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Simulate S same-shape traces on the Pallas grid.
 
@@ -781,6 +859,9 @@ def cache_sim_pallas(
         byte-identical to before the option existed.
       groups: (n_objects,) int32 id -> group labels in [0, n_groups), shared
         by all samples (``workloads.tenant_groups``).
+      interpret: run the Pallas interpreter instead of compiling with Mosaic
+        (the only way to run the kernel off-TPU; ``ops.cache_sim`` picks it
+        from the backend).
 
     The defaults mirror ``jax_cache.PolicySpec`` exactly, so identical
     arguments produce bit-identical state across the two tiers.
@@ -824,7 +905,8 @@ def cache_sim_pallas(
         raise ValueError("max_victims is a byte-capacity (capacity_bytes) option")
     max_victims = (max_victims or registry.DEFAULT_MAX_VICTIMS) if capacity_bytes else 0
     s, t = traces.shape
-    n_pad = _round_up(max(n_objects, 128), 128)
+    n_rows = _tile_rows(n_objects)
+    n_pad = n_rows * 128
     if kind in ("plfua", "plfua_dyn"):
         hot_size = min(n_objects, hot_size or 2 * capacity)
     # normalise options the kind ignores to 0 so they can't create spurious
@@ -860,50 +942,49 @@ def cache_sim_pallas(
         max_victims=max_victims,
         n_groups=n_groups,
     )
+    # per-sample blocks squeeze the sample dim; the last two dims are whole
+    # dense (rows, 128) arrays, which is what Mosaic's (8, 128) rule admits
+    per_sample = lambda *tail: pl.BlockSpec(
+        (None, *tail), lambda i: (i,) + (0,) * len(tail)
+    )
+    shared = lambda rows: pl.BlockSpec((rows, 128), lambda i: (0, 0))
     out_specs = [
-        pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        pl.BlockSpec((1, n_pad), lambda i: (i, 0)),
-        pl.BlockSpec((1, n_pad), lambda i: (i, 0)),
+        pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0), memory_space=pltpu.SMEM),
+        per_sample(n_rows, 128),
+        per_sample(n_rows, 128),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((s, 1), jnp.int32),
-        jax.ShapeDtypeStruct((s, n_pad), jnp.int32),
-        jax.ShapeDtypeStruct((s, n_pad), jnp.int32),
+        jax.ShapeDtypeStruct((s, 1, 1), jnp.int32),
+        jax.ShapeDtypeStruct((s, n_rows, 128), jnp.int32),
+        jax.ShapeDtypeStruct((s, n_rows, 128), jnp.int32),
     ]
     if telemetry_window:
         tel_rows = _TEL_ROWS * (n_groups or 1)
-        out_specs.append(pl.BlockSpec((1, tel_rows, n_w_pad), lambda i: (i, 0, 0)))
+        out_specs.append(per_sample(tel_rows, n_w_pad))
         out_shape.append(jax.ShapeDtypeStruct((s, tel_rows, n_w_pad), jnp.int32))
-    in_specs = [pl.BlockSpec((1, t), lambda i: (i, 0))]
-    inputs = [traces.astype(jnp.int32)]
+    t_rows = _tile_rows(t)
+    traces = jnp.pad(traces.astype(jnp.int32), ((0, 0), (0, t_rows * 128 - t)))
+    in_specs = [per_sample(t_rows, 128)]
+    inputs = [traces.reshape(s, t_rows, 128)]
+
+    def per_id(name, values, pad):
+        # grid-shared per-id row (jnp throughout: it may be a tracer under
+        # the jitted ops.cache_sim)
+        v = jnp.asarray(values, jnp.int32)
+        if v.shape != (n_objects,):
+            raise ValueError(f"{name} must have shape ({n_objects},), got {v.shape}")
+        in_specs.append(shared(n_rows))
+        inputs.append(jnp.pad(v, (0, n_pad - n_objects), constant_values=pad)
+                      .reshape(n_rows, 128))
+
     if capacity_bytes or kind == "gdsf":
-        # grid-shared (1, n_pad) sizes row; padding lanes are size 1 so the
-        # unit-size fallback and the padded tail share one code path (jnp
-        # throughout: sizes may be a tracer under the jitted ops.cache_sim)
-        if sizes is None:
-            sizes_row = jnp.ones((1, n_pad), jnp.int32)
-        else:
-            sz = jnp.asarray(sizes, jnp.int32)
-            if sz.shape != (n_objects,):
-                raise ValueError(
-                    f"sizes must have shape ({n_objects},), got {sz.shape}"
-                )
-            sizes_row = jnp.concatenate(
-                [sz, jnp.ones((n_pad - n_objects,), jnp.int32)]
-            )[None, :]
-        in_specs.append(pl.BlockSpec((1, n_pad), lambda i: (0, 0)))
-        inputs.append(sizes_row)
+        # padding lanes are size 1 so the unit-size fallback and the padded
+        # tail share one code path
+        per_id("sizes", jnp.ones((n_objects,)) if sizes is None else sizes, 1)
     if telemetry_window and n_groups:
-        # grid-shared (1, n_pad) id -> group row; padding lanes get group 0 —
-        # harmless because padding ids are never requested, cached, or hot
-        g = jnp.asarray(groups, jnp.int32)
-        if g.shape != (n_objects,):
-            raise ValueError(f"groups must have shape ({n_objects},), got {g.shape}")
-        groups_row = jnp.concatenate(
-            [g, jnp.zeros((n_pad - n_objects,), jnp.int32)]
-        )[None, :]
-        in_specs.append(pl.BlockSpec((1, n_pad), lambda i: (0, 0)))
-        inputs.append(groups_row)
+        # padding lanes get group 0 — harmless because padding ids are never
+        # requested, cached, or hot
+        per_id("groups", groups, 0)
     out = pl.pallas_call(
         kernel,
         grid=(s,),
@@ -912,8 +993,9 @@ def cache_sim_pallas(
         out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
-    hits, freq, cache = out[0], out[1], out[2]
-    result = (hits[:, 0], freq[:, :n_objects], cache[:, :n_objects].astype(bool))
+    hits = out[0].reshape(s)
+    freq, cache = (o.reshape(s, n_pad)[:, :n_objects] for o in out[1:3])
+    result = (hits, freq, cache.astype(bool))
     if telemetry_window:
         if n_groups:
             # (S, 16G, w_pad) -> (S, G, 16, n_w) -> (S, n_w, G, N_METRICS)

@@ -25,17 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params_cls():
-    """jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x."""
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise AttributeError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version"
-    )
-
 NEG_INF = -1e30
 
 
@@ -165,7 +154,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -153,10 +153,7 @@ class Roofline:
 
 
 def cost_dict(cost) -> dict:
-    """Normalise ``compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones a flat dict."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
+    """``compiled.cost_analysis()`` as a dict (it may be None)."""
     return cost or {}
 
 

@@ -284,7 +284,7 @@ def test_sharded_paths_match_on_forced_devices():
     )
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
-        cwd=REPO_ROOT,
+        cwd=REPO_ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert "SHARDED_OK" in out.stdout, (out.stdout[-1000:], out.stderr[-3000:])
 
@@ -311,7 +311,7 @@ def test_bench_record_roundtrip(tmp_path):
         [sys.executable, "-m", "benchmarks.run", "--only", "fleet_depth",
          "--record", str(out_path)],
         capture_output=True, text=True, cwd=REPO_ROOT,
-        env={**os.environ, "PYTHONPATH": "src"},
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
         timeout=900,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]

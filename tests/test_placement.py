@@ -30,6 +30,7 @@ examples when the real package is absent), with shapes pinned to small
 fixed sets so jit recompiles stay bounded.
 """
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -48,6 +49,8 @@ from repro.core import jax_cache
 from repro.fleet import placement
 
 REPO_ROOT = str(pathlib.Path(__file__).resolve().parents[1])
+# child processes run on forced host devices, never on an accelerator
+CPU_CHILD_ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
 N, T = 96, 700
 PLACEMENTS = ("lce", "lcd", "prob(0.5)", "admit")
@@ -447,7 +450,7 @@ def test_prob_placement_deterministic_across_processes():
     runs = [
         subprocess.run(
             [sys.executable, "-c", script], capture_output=True, text=True,
-            cwd=REPO_ROOT, timeout=600,
+            cwd=REPO_ROOT, env=CPU_CHILD_ENV, timeout=600,
         )
         for _ in range(2)
     ]
@@ -517,7 +520,7 @@ def test_sharded_placement_paths_match_on_forced_devices():
     )
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
-        cwd=REPO_ROOT, timeout=900,
+        cwd=REPO_ROOT, env=CPU_CHILD_ENV, timeout=900,
     )
     assert "PLACED_SHARDED_OK" in out.stdout, (
         out.stdout[-1000:], out.stderr[-3000:],

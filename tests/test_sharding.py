@@ -1,4 +1,5 @@
 """Sharding-rule unit tests + a real multi-device compile in a subprocess."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -136,5 +137,7 @@ def test_small_mesh_compile_with_rules():
         assert bool(jnp.isfinite(m["loss"])), m
         print("SPMD_OK", float(m["loss"]))
     """)
-    out = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True, cwd="/root/repo")
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True,
+                         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert "SPMD_OK" in out.stdout, (out.stdout[-1000:], out.stderr[-3000:])

@@ -2,6 +2,7 @@
 checkpoint atomicity/integrity/elasticity, preemption-resume, compression
 unbiasedness, data determinism."""
 import json
+import os
 import shutil
 import zlib
 from pathlib import Path
@@ -145,7 +146,9 @@ def test_elastic_restore_different_mesh(tmp_path):
         print("ELASTIC_OK")
     """)
     out = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True, cwd="/root/repo"
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        cwd=str(Path(__file__).resolve().parent.parent),
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert "ELASTIC_OK" in out.stdout, out.stderr[-2000:]
 
