@@ -173,8 +173,11 @@ def init_state(spec: PolicySpec) -> dict[str, jax.Array]:
 
 
 def _masked_argmin(values: jax.Array, mask: jax.Array) -> jax.Array:
-    """argmin over ``values`` where mask, lowest index on ties (int32 values)."""
-    return jnp.argmin(jnp.where(mask, values, _I32_MAX)).astype(jnp.int32)
+    """argmin over ``values`` where mask, lowest index on ties (int32 values).
+    Every kind's victim search in every engine comes through here, so its
+    operations carry the ``repro.victim`` scope in a profile."""
+    with jax.named_scope("repro.victim"):
+        return jnp.argmin(jnp.where(mask, values, _I32_MAX)).astype(jnp.int32)
 
 
 def _sz(sizes: jax.Array | None, i: jax.Array) -> jax.Array:
@@ -548,12 +551,14 @@ def step(
 def refresh_hot(spec: PolicySpec, state: dict[str, jax.Array]) -> dict[str, jax.Array]:
     """plfua_dyn hot-set refresh: new mask = sketch top-k (est desc, ties to
     the lowest id — lax.top_k's order, matching the reference's lexsort), then
-    halve the sketch so estimates stay recency-weighted."""
-    table = jnp.asarray(spec._bucket_table())
-    est = sketch.rows_estimate_all(state["sketch"], table)
-    _, top = jax.lax.top_k(est, spec.effective_hot)
-    hot = jnp.zeros((spec.n_objects,), jnp.bool_).at[top].set(True)
-    return {**state, "hot": hot, "sketch": sketch.rows_halve(state["sketch"])}
+    halve the sketch so estimates stay recency-weighted (profile scope
+    ``repro.refresh``, in every engine)."""
+    with jax.named_scope("repro.refresh"):
+        table = jnp.asarray(spec._bucket_table())
+        est = sketch.rows_estimate_all(state["sketch"], table)
+        _, top = jax.lax.top_k(est, spec.effective_hot)
+        hot = jnp.zeros((spec.n_objects,), jnp.bool_).at[top].set(True)
+        return {**state, "hot": hot, "sketch": sketch.rows_halve(state["sketch"])}
 
 
 def _step_events(spec: PolicySpec, s, ns, hit, x, a, sizes=None, og=None):
@@ -738,11 +743,12 @@ def run_chunk(spec: PolicySpec, state, trace, t0=0, sizes=None):
     re-materializes donated arguments per call."""
     if sizes is not None:
         sizes = jnp.asarray(sizes, jnp.int32)
-    if spec.kind == "plfua_dyn":
-        return stream_chunked_scan(spec, state, trace, t0=t0, sizes=sizes)
-    return jax.lax.scan(
-        lambda s, x: step(spec, s, x, sizes=sizes), state, trace.astype(jnp.int32)
-    )
+    with jax.named_scope("repro.step"):
+        if spec.kind == "plfua_dyn":
+            return stream_chunked_scan(spec, state, trace, t0=t0, sizes=sizes)
+        return jax.lax.scan(
+            lambda s, x: step(spec, s, x, sizes=sizes), state, trace.astype(jnp.int32)
+        )
 
 
 def instrumented_scan(
@@ -858,15 +864,17 @@ def simulate(
     if sizes is not None:
         sizes = jnp.asarray(sizes, jnp.int32)
     if telemetry is None:
-        if spec.kind == "plfua_dyn":
-            state, hits = _chunked_scan(spec, state, trace, sizes=sizes)
-        else:
-            state, hits = jax.lax.scan(
-                lambda s, x: step(spec, s, x, sizes=sizes), state, trace
-            )
+        with jax.named_scope("repro.step"):
+            if spec.kind == "plfua_dyn":
+                state, hits = _chunked_scan(spec, state, trace, sizes=sizes)
+            else:
+                state, hits = jax.lax.scan(
+                    lambda s, x: step(spec, s, x, sizes=sizes), state, trace
+                )
         return hits, state
     og, groups_t = group_scatter_arrays(telemetry, groups, trace)
-    state, hits, events = instrumented_scan(spec, state, trace, sizes=sizes, og=og)
+    with jax.named_scope("repro.step"):
+        state, hits, events = instrumented_scan(spec, state, trace, sizes=sizes, og=og)
     series = telemetry_series(
         spec, telemetry, trace.shape[0], hits, events, groups_t=groups_t
     )

@@ -441,125 +441,127 @@ def _placed_prelude(
         states, pstates, fills, admitted = carry
         t, x, valid, nodes = inp
         # ---- probe the miss path bottom-up on pre-update membership
-        consulted, hits = [], []
-        demand = valid
-        if edge_axis is not None:
-            offset = jax.lax.axis_index(edge_axis).astype(jnp.int32) * n_local
-            local0 = nodes[0] - offset
-            own0 = (local0 >= 0) & (local0 < n_local)
-            node0 = jnp.clip(local0, 0, n_local - 1)
-        else:
-            own0, node0 = jnp.bool_(True), nodes[0]
-        for l in range(L):
-            if l == 0:
-                in_c = own0 & states[0]["in_cache"][node0, x]
-                if edge_axis is not None:
-                    # one collective rebuilds the global edge-served bit
-                    # (exactly one device owns the assigned edge)
-                    in_c = jax.lax.psum(in_c.astype(jnp.int32), edge_axis) > 0
+        with jax.named_scope("repro.probe"):
+            consulted, hits = [], []
+            demand = valid
+            if edge_axis is not None:
+                offset = jax.lax.axis_index(edge_axis).astype(jnp.int32) * n_local
+                local0 = nodes[0] - offset
+                own0 = (local0 >= 0) & (local0 < n_local)
+                node0 = jnp.clip(local0, 0, n_local - 1)
             else:
-                in_c = states[l]["in_cache"][nodes[l], x]
-            consulted.append(demand)
-            hits.append(demand & in_c)
-            demand = demand & ~in_c
-        serve = jnp.int32(L)  # L = served at origin
-        for l in reversed(range(L)):
-            serve = jnp.where(hits[l], jnp.int32(l), serve)
+                own0, node0 = jnp.bool_(True), nodes[0]
+            for l in range(L):
+                if l == 0:
+                    in_c = own0 & states[0]["in_cache"][node0, x]
+                    if edge_axis is not None:
+                        # one collective rebuilds the global edge-served bit
+                        # (exactly one device owns the assigned edge)
+                        in_c = jax.lax.psum(in_c.astype(jnp.int32), edge_axis) > 0
+                else:
+                    in_c = states[l]["in_cache"][nodes[l], x]
+                consulted.append(demand)
+                hits.append(demand & in_c)
+                demand = demand & ~in_c
+            serve = jnp.int32(L)  # L = served at origin
+            for l in reversed(range(L)):
+                serve = jnp.where(hits[l], jnp.int32(l), serve)
         # ---- fill-gated update of the one consulted node per level
         new_states, new_fills, new_admitted, tel = [], [], [], []
         new_pstates = dict(pstates)
         for l in range(L):
-            spec = specs[l]
-            node = node0 if l == 0 else nodes[l]
-            act = consulted[l] & (own0 if l == 0 else True)
-            st = jax.tree_util.tree_map(lambda a: a[node], states[l])
-            cap = caps[l][node]
-            cap_b = caps_b[l][node] if spec.capacity_bytes else None
-            pk, pp = parsed[l]
-            if pk == "lce":
-                fill = None
-            elif pk == "lcd":
-                fill = serve == l + 1
-            elif pk == "prob":
-                fill = (serve == l + 1) | placement_mod.prob_fill(t, l, pp, jnp)
-            else:  # admit: feed + age the placement sketch, then duel
-                ps = pstates[l]
-                idx = admit_tables[l][x]
-                rows = sketch.rows_add(ps["rows"][node], idx)
-                seen = ps["seen"][node] + 1
-                age = seen >= admit_windows[l]
-                rows = jnp.where(age, sketch.rows_halve(rows), rows)
-                seen = jnp.where(age, 0, seen)
-                victim = jax_cache._masked_argmin(
-                    _victim_key(spec, st), st["in_cache"]
+            with jax.named_scope(f"repro.level{l}"):
+                spec = specs[l]
+                node = node0 if l == 0 else nodes[l]
+                act = consulted[l] & (own0 if l == 0 else True)
+                st = jax.tree_util.tree_map(lambda a: a[node], states[l])
+                cap = caps[l][node]
+                cap_b = caps_b[l][node] if spec.capacity_bytes else None
+                pk, pp = parsed[l]
+                if pk == "lce":
+                    fill = None
+                elif pk == "lcd":
+                    fill = serve == l + 1
+                elif pk == "prob":
+                    fill = (serve == l + 1) | placement_mod.prob_fill(t, l, pp, jnp)
+                else:  # admit: feed + age the placement sketch, then duel
+                    ps = pstates[l]
+                    idx = admit_tables[l][x]
+                    rows = sketch.rows_add(ps["rows"][node], idx)
+                    seen = ps["seen"][node] + 1
+                    age = seen >= admit_windows[l]
+                    rows = jnp.where(age, sketch.rows_halve(rows), rows)
+                    seen = jnp.where(age, 0, seen)
+                    victim = jax_cache._masked_argmin(
+                        _victim_key(spec, st), st["in_cache"]
+                    )
+                    if spec.capacity_bytes:
+                        # byte mode: "full" = does not fit as-is (cf. tinylfu)
+                        size_x = jnp.int32(1) if sizes is None else sizes[x]
+                        full = st["bytes"] + size_x > cap_b
+                    else:
+                        full = st["count"] >= cap
+                    est_x = sketch.rows_estimate(rows, idx)
+                    est_v = sketch.rows_estimate(rows, admit_tables[l][victim])
+                    fill = (~full) | (est_x > est_v)
+                    new_pstates[l] = dict(
+                        rows=ps["rows"].at[node].set(
+                            jnp.where(act, rows, ps["rows"][node])
+                        ),
+                        seen=ps["seen"].at[node].set(
+                            jnp.where(act, seen, ps["seen"][node])
+                        ),
+                    )
+                ns, hit = jax_cache.step(
+                    spec, st, x, cap, fill=fill, sizes=sizes, cap_bytes=cap_b
                 )
-                if spec.capacity_bytes:
-                    # byte mode: "full" = does not fit as-is (cf. tinylfu)
-                    size_x = jnp.int32(1) if sizes is None else sizes[x]
-                    full = st["bytes"] + size_x > cap_b
+                insert = act & (~hit) & ns["in_cache"][x]
+                new_states.append(
+                    jax.tree_util.tree_map(
+                        lambda old, new: old.at[node].set(
+                            jnp.where(act, new, old[node])
+                        ),
+                        states[l],
+                        ns,
+                    )
+                )
+                if instrument:
+                    gate = jnp.bool_(True) if fill is None else fill
+                    tel_l = {
+                        "fill": insert,
+                        # int32 victim count: byte mode can evict several per
+                        # insert; in object mode this is the old 0/1 event
+                        "evict": jnp.where(act, st["count"] - ns["count"], 0)
+                        + insert.astype(jnp.int32),
+                        "offer": act & (~hit) & gate,
+                        # post-step occupancy snapshot of the whole node fleet
+                        "count": new_states[l]["count"],
+                    }
+                    if og is not None:
+                        # victim-group counts at the consulted node (membership
+                        # diff = exactly the victims; masked like the scalar) and
+                        # the whole node fleet's per-group occupancy snapshot
+                        vmask = st["in_cache"] & ~ns["in_cache"]
+                        tel_l["evict_g"] = jnp.where(
+                            act, vmask.astype(jnp.int32) @ og, 0
+                        )
+                        tel_l["count_g"] = (
+                            new_states[l]["in_cache"].astype(jnp.int32) @ og
+                        )
+                    if spec.kind == "tinylfu":
+                        tel_l["aging"] = act & (ns["seen"] == 0)
+                    tel.append(tel_l)
+                new_fills.append(fills[l].at[node].add(insert.astype(jnp.int32)))
+                # same admitted_requests conventions as tier_counters
+                if spec.kind == "plfua":
+                    adm = act & st["hot"][x]
+                elif spec.kind in jax_cache.SKETCH_POLICY_KINDS:
+                    adm = (act & hit) | insert
                 else:
-                    full = st["count"] >= cap
-                est_x = sketch.rows_estimate(rows, idx)
-                est_v = sketch.rows_estimate(rows, admit_tables[l][victim])
-                fill = (~full) | (est_x > est_v)
-                new_pstates[l] = dict(
-                    rows=ps["rows"].at[node].set(
-                        jnp.where(act, rows, ps["rows"][node])
-                    ),
-                    seen=ps["seen"].at[node].set(
-                        jnp.where(act, seen, ps["seen"][node])
-                    ),
+                    adm = act
+                new_admitted.append(
+                    admitted[l].at[node].add(adm.astype(jnp.int32))
                 )
-            ns, hit = jax_cache.step(
-                spec, st, x, cap, fill=fill, sizes=sizes, cap_bytes=cap_b
-            )
-            insert = act & (~hit) & ns["in_cache"][x]
-            new_states.append(
-                jax.tree_util.tree_map(
-                    lambda old, new: old.at[node].set(
-                        jnp.where(act, new, old[node])
-                    ),
-                    states[l],
-                    ns,
-                )
-            )
-            if instrument:
-                gate = jnp.bool_(True) if fill is None else fill
-                tel_l = {
-                    "fill": insert,
-                    # int32 victim count: byte mode can evict several per
-                    # insert; in object mode this is the old 0/1 event
-                    "evict": jnp.where(act, st["count"] - ns["count"], 0)
-                    + insert.astype(jnp.int32),
-                    "offer": act & (~hit) & gate,
-                    # post-step occupancy snapshot of the whole node fleet
-                    "count": new_states[l]["count"],
-                }
-                if og is not None:
-                    # victim-group counts at the consulted node (membership
-                    # diff = exactly the victims; masked like the scalar) and
-                    # the whole node fleet's per-group occupancy snapshot
-                    vmask = st["in_cache"] & ~ns["in_cache"]
-                    tel_l["evict_g"] = jnp.where(
-                        act, vmask.astype(jnp.int32) @ og, 0
-                    )
-                    tel_l["count_g"] = (
-                        new_states[l]["in_cache"].astype(jnp.int32) @ og
-                    )
-                if spec.kind == "tinylfu":
-                    tel_l["aging"] = act & (ns["seen"] == 0)
-                tel.append(tel_l)
-            new_fills.append(fills[l].at[node].add(insert.astype(jnp.int32)))
-            # same admitted_requests conventions as tier_counters
-            if spec.kind == "plfua":
-                adm = act & st["hot"][x]
-            elif spec.kind in jax_cache.SKETCH_POLICY_KINDS:
-                adm = (act & hit) | insert
-            else:
-                adm = act
-            new_admitted.append(
-                admitted[l].at[node].add(adm.astype(jnp.int32))
-            )
         carry = (
             tuple(new_states),
             new_pstates,

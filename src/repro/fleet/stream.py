@@ -154,7 +154,11 @@ class StreamStats:
     (``sim.tier_counters`` / ``assemble_placed``); the fast path reports the
     reduced dict its carry can derive (requests/hits/count[, inserts]).
     ``telemetry``/``telemetry_pressure`` are the stitched per-level series,
-    shaped exactly like ``simulate_fleet``'s on the concatenated trace."""
+    shaped exactly like ``simulate_fleet``'s on the concatenated trace.
+    ``lanes`` is the fast path's compact lanes stepped (chunks x ``P +
+    chunk_len``) and ``lanes_valid`` those holding a real object (an int32
+    device counter, like ``hits``, exact below 2**31); both are ``None`` off
+    the fast path."""
 
     requests: int
     chunks: int
@@ -165,6 +169,8 @@ class StreamStats:
     elapsed_s: float | None = None
     telemetry: tuple | None = None
     telemetry_pressure: tuple | None = None
+    lanes: int | None = None
+    lanes_valid: int | None = None
 
     @property
     def total_chr(self) -> float:
@@ -203,15 +209,16 @@ def _stream_masked_scan(
     """The streaming twin of ``sim.masked_scan``: identical for every kind
     except plfua_dyn, which routes through ``stream_chunked_scan`` so its
     global-time refresh consults the traced stream position ``t0``."""
-    if spec.kind == "plfua_dyn":
-        return jax_cache.stream_chunked_scan(
-            spec, state, trace, active, cap, t0=t0, instrument=instrument,
-            sizes=sizes, cap_bytes=cap_bytes, og=og,
+    with jax.named_scope("repro.step"):
+        if spec.kind == "plfua_dyn":
+            return jax_cache.stream_chunked_scan(
+                spec, state, trace, active, cap, t0=t0, instrument=instrument,
+                sizes=sizes, cap_bytes=cap_bytes, og=og,
+            )
+        return sim_mod.masked_scan(
+            spec, state, trace, active, cap, instrument=instrument, sizes=sizes,
+            cap_bytes=cap_bytes, og=og,
         )
-    return sim_mod.masked_scan(
-        spec, state, trace, active, cap, instrument=instrument, sizes=sizes,
-        cap_bytes=cap_bytes, og=og,
-    )
 
 
 def _acc_keys(spec: PolicySpec, sized: bool) -> tuple[str, ...]:
@@ -304,7 +311,7 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
     instrument = telemetry is not None
     grouped = og is not None
 
-    def chunk_fn(carry, trace, assignment):
+    def level_major_chunk(carry, trace, assignment):
         t0 = carry["t0"]
         trace = trace.astype(jnp.int32)
         assigns = sim_mod.level_assignments(topo, trace, assignment)
@@ -314,53 +321,56 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
         new_states, new_acc = [], []
         hit_lv, node_hit, series, pressure = [], [], [], []
         for l, specs in enumerate(topo.levels):
-            s0 = specs[0]
-            K = len(specs)
-            active = (
-                assigns[l][None, :] == jnp.arange(K, dtype=jnp.int32)[:, None]
-            ) & demand[None, :]
-            caps = jnp.array([s.capacity for s in specs], jnp.int32)
-            if s0.capacity_bytes:
-                caps_b = jnp.array([s.capacity_bytes for s in specs], jnp.int32)
-                out = jax.vmap(
-                    lambda st, act, cap, capb: _stream_masked_scan(
-                        s0, st, trace, act, cap, t0=t0, instrument=instrument,
-                        sizes=sizes, cap_bytes=capb, og=og,
-                    )
-                )(carry["states"][l], active, caps, caps_b)
-            else:
-                out = jax.vmap(
-                    lambda st, act, cap: _stream_masked_scan(
-                        s0, st, trace, act, cap, t0=t0, instrument=instrument,
-                        sizes=sizes, og=og,
-                    )
-                )(carry["states"][l], active, caps)
-            if instrument:
-                states_l, hits, events = out
-                series.append(
-                    jax_cache.telemetry_series(
-                        s0, telemetry, G, hits, events, active=active,
-                        groups_t=groups_t, chunk_len=_sub_len(s0, G),
-                    )
-                )
-                if grouped:
-                    pressure.append(
-                        telemetry_spec.windowed_pressure(
-                            telemetry.window, groups_t, events["evict_g"], xp=jnp
+            with jax.named_scope(f"repro.level{l}"):
+                s0 = specs[0]
+                K = len(specs)
+                active = (
+                    assigns[l][None, :] == jnp.arange(K, dtype=jnp.int32)[:, None]
+                ) & demand[None, :]
+                caps = jnp.array([s.capacity for s in specs], jnp.int32)
+                if s0.capacity_bytes:
+                    caps_b = jnp.array([s.capacity_bytes for s in specs], jnp.int32)
+                    out = jax.vmap(
+                        lambda st, act, cap, capb: _stream_masked_scan(
+                            s0, st, trace, act, cap, t0=t0, instrument=instrument,
+                            sizes=sizes, cap_bytes=capb, og=og,
                         )
+                    )(carry["states"][l], active, caps, caps_b)
+                else:
+                    out = jax.vmap(
+                        lambda st, act, cap: _stream_masked_scan(
+                            s0, st, trace, act, cap, t0=t0, instrument=instrument,
+                            sizes=sizes, og=og,
+                        )
+                    )(carry["states"][l], active, caps)
+                if instrument:
+                    states_l, hits, events = out
+                    with jax.named_scope("repro.telemetry"):
+                        series.append(
+                            jax_cache.telemetry_series(
+                                s0, telemetry, G, hits, events, active=active,
+                                groups_t=groups_t, chunk_len=_sub_len(s0, G),
+                            )
+                        )
+                        if grouped:
+                            pressure.append(
+                                telemetry_spec.windowed_pressure(
+                                    telemetry.window, groups_t, events["evict_g"],
+                                    xp=jnp,
+                                )
+                            )
+                else:
+                    states_l, hits = out
+                new_states.append(states_l)
+                new_acc.append(
+                    _accumulate_level(
+                        s0, carry["acc"][l], active, hits, trace, states_l, sz_t
                     )
-            else:
-                states_l, hits = out
-            new_states.append(states_l)
-            new_acc.append(
-                _accumulate_level(
-                    s0, carry["acc"][l], active, hits, trace, states_l, sz_t
                 )
-            )
-            node_hit.append(hits)
-            hit_l = hits.any(axis=0)
-            hit_lv.append(hit_l)
-            demand = demand & ~hit_l
+                node_hit.append(hits)
+                hit_l = hits.any(axis=0)
+                hit_lv.append(hit_l)
+                demand = demand & ~hit_l
         new_carry = {
             "states": tuple(new_states),
             "acc": tuple(new_acc),
@@ -384,7 +394,7 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
         "origin": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    return jax.jit(chunk_fn, donate_argnums=0), carry0
+    return jax.jit(level_major_chunk, donate_argnums=0), carry0
 
 
 # ------------------------------------------------------------ placed chunks
@@ -405,7 +415,7 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
         specs, dyn_levels, step_t, instrument=instrument, og=og
     )
 
-    def chunk_fn(carry, trace, assignment):
+    def placed_chunk(carry, trace, assignment):
         t0 = carry["t0"]
         trace = trace.astype(jnp.int32)
         assigns = sim_mod.level_assignments(topo, trace, assignment)
@@ -425,19 +435,20 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
         else:
             fire = jnp.zeros((n_sub, 0), jnp.bool_)
         tile = lambda a: a.reshape(n_sub, sub, *a.shape[1:])
-        placed, out = jax.lax.scan(
-            chunk_body,
-            carry["placed"],
-            (
+        with jax.named_scope("repro.step"):
+            placed, out = jax.lax.scan(
+                chunk_body,
+                carry["placed"],
                 (
-                    tile(t_arr),
-                    tile(trace),
-                    tile(valid),
-                    tuple(tile(a) for a in assigns),
+                    (
+                        tile(t_arr),
+                        tile(trace),
+                        tile(valid),
+                        tuple(tile(a) for a in assigns),
+                    ),
+                    fire,
                 ),
-                fire,
-            ),
-        )
+            )
         untiled = sim_mod._placed_untile(
             out, G, topo.n_levels, dyn_levels, fire, instrument=instrument, og=og
         )
@@ -466,65 +477,66 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
             new_acc.append(acc_l)
             node_hit.append(nh)
             if instrument:
-                ev = tel_lv[l]
-                per_node = lambda s: active & s[None, :]
-                aging = ev.get("aging")
-                if grouped:
-                    evict_g = active[:, :, None] * ev["evict_g"][None, :, :]
-                    series.append(
-                        telemetry_spec.grouped_series_from_run(
-                            telemetry.window,
-                            G,
-                            telemetry.n_groups,
-                            groups_t,
-                            hits=nh,
-                            active=active,
-                            fills=per_node(ev["fill"]),
-                            evictions_g=evict_g,
-                            occupancy_g=ev["count_g"],
-                            offers=per_node(ev["offer"]),
-                            aging=None if aging is None else per_node(aging),
-                            fired=ev.get("fired"),
-                            churn_g=ev.get("churn_g"),
-                            hit_bytes=None if sz_t is None else nh * sz_t[None, :],
-                            miss_bytes=(
-                                None
-                                if sz_t is None
-                                else (active & ~nh) * sz_t[None, :]
-                            ),
-                            chunk_len=sub,
-                            xp=jnp,
+                with jax.named_scope("repro.telemetry"):
+                    ev = tel_lv[l]
+                    per_node = lambda s: active & s[None, :]
+                    aging = ev.get("aging")
+                    if grouped:
+                        evict_g = active[:, :, None] * ev["evict_g"][None, :, :]
+                        series.append(
+                            telemetry_spec.grouped_series_from_run(
+                                telemetry.window,
+                                G,
+                                telemetry.n_groups,
+                                groups_t,
+                                hits=nh,
+                                active=active,
+                                fills=per_node(ev["fill"]),
+                                evictions_g=evict_g,
+                                occupancy_g=ev["count_g"],
+                                offers=per_node(ev["offer"]),
+                                aging=None if aging is None else per_node(aging),
+                                fired=ev.get("fired"),
+                                churn_g=ev.get("churn_g"),
+                                hit_bytes=None if sz_t is None else nh * sz_t[None, :],
+                                miss_bytes=(
+                                    None
+                                    if sz_t is None
+                                    else (active & ~nh) * sz_t[None, :]
+                                ),
+                                chunk_len=sub,
+                                xp=jnp,
+                            )
                         )
-                    )
-                    pressure.append(
-                        telemetry_spec.windowed_pressure(
-                            telemetry.window, groups_t, evict_g, xp=jnp
+                        pressure.append(
+                            telemetry_spec.windowed_pressure(
+                                telemetry.window, groups_t, evict_g, xp=jnp
+                            )
                         )
-                    )
-                else:
-                    series.append(
-                        telemetry_spec.series_from_run(
-                            telemetry.window,
-                            G,
-                            hits=nh,
-                            active=active,
-                            fills=per_node(ev["fill"]),
-                            evictions=active * ev["evict"][None, :],
-                            occupancy=ev["count"],
-                            offers=per_node(ev["offer"]),
-                            aging=None if aging is None else per_node(aging),
-                            fired=ev.get("fired"),
-                            churn=ev.get("churn"),
-                            hit_bytes=None if sz_t is None else nh * sz_t[None, :],
-                            miss_bytes=(
-                                None
-                                if sz_t is None
-                                else (active & ~nh) * sz_t[None, :]
-                            ),
-                            chunk_len=sub,
-                            xp=jnp,
+                    else:
+                        series.append(
+                            telemetry_spec.series_from_run(
+                                telemetry.window,
+                                G,
+                                hits=nh,
+                                active=active,
+                                fills=per_node(ev["fill"]),
+                                evictions=active * ev["evict"][None, :],
+                                occupancy=ev["count"],
+                                offers=per_node(ev["offer"]),
+                                aging=None if aging is None else per_node(aging),
+                                fired=ev.get("fired"),
+                                churn=ev.get("churn"),
+                                hit_bytes=None if sz_t is None else nh * sz_t[None, :],
+                                miss_bytes=(
+                                    None
+                                    if sz_t is None
+                                    else (active & ~nh) * sz_t[None, :]
+                                ),
+                                chunk_len=sub,
+                                xp=jnp,
+                            )
                         )
-                    )
             demand = demand & ~hit_lv[l]
         new_carry = {
             "placed": placed,
@@ -549,7 +561,7 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
         "origin": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    return jax.jit(chunk_fn, donate_argnums=0), carry0
+    return jax.jit(placed_chunk, donate_argnums=0), carry0
 
 
 # --------------------------------------------------- fast compact-lane path
@@ -570,50 +582,54 @@ def _build_fast(cfg: StreamConfig, sizes):
     big_table = spec._bucket_table() if sketchy else None
     big_bloom = spec._bloom_table() if spec.kind == "tinylfu" and spec.doorkeeper else None
 
-    def chunk_fn(carry, trace):
+    def fast_chunk(carry, trace):
         state, roster, t0 = carry["state"], carry["roster"], carry["t0"]
         xs = trace.astype(jnp.int32)
         # ---- candidates: the P lex-smallest (eviction_key, id) cached pairs,
         # selected over the roster (every resident), sentinel-padded with N
-        key = sim_mod._victim_key(spec, state)
-        rc = jnp.minimum(roster, N - 1)
-        rkey = jnp.where(roster < N, key[rc], jax_cache._I32_MAX)
-        _, sid = jax.lax.sort((rkey, roster), num_keys=2)
-        cand = jax.lax.slice_in_dim(sid, 0, P)
+        with jax.named_scope("repro.select"):
+            key = sim_mod._victim_key(spec, state)
+            rc = jnp.minimum(roster, N - 1)
+            rkey = jnp.where(roster < N, key[rc], jax_cache._I32_MAX)
+            _, sid = jax.lax.sort((rkey, roster), num_keys=2)
+            cand = jax.lax.slice_in_dim(sid, 0, P)
         # ---- lanes: candidates ∪ chunk ids, id-sorted, deduped to sentinel
-        ids = jnp.sort(jnp.concatenate([cand, xs]))
-        dup = jnp.concatenate([jnp.zeros((1,), jnp.bool_), ids[1:] == ids[:-1]])
-        ids = jnp.sort(jnp.where(dup, N, ids))
-        valid = ids < N
-        idc = jnp.minimum(ids, N - 1)
-        cstate = {}
-        for k, v in state.items():
-            if k == "in_cache":
-                # invalid lanes must read not-cached (they hold garbage rows)
-                cstate[k] = valid & v[idc]
-            elif k in _PER_OBJECT_FIELDS:
-                cstate[k] = v[idc]
-            else:
-                cstate[k] = v
-        table_c = None if big_table is None else jnp.asarray(big_table)[idc]
-        bloom_c = None if big_bloom is None else jnp.asarray(big_bloom)[idc]
-        sizes_c = None if sizes is None else sizes[idc]
-        lx = jnp.searchsorted(ids, xs).astype(jnp.int32)
+        with jax.named_scope("repro.lanes"):
+            ids = jnp.sort(jnp.concatenate([cand, xs]))
+            dup = jnp.concatenate([jnp.zeros((1,), jnp.bool_), ids[1:] == ids[:-1]])
+            ids = jnp.sort(jnp.where(dup, N, ids))
+            valid = ids < N
+            idc = jnp.minimum(ids, N - 1)
+            cstate = {}
+            for k, v in state.items():
+                if k == "in_cache":
+                    # invalid lanes must read not-cached (they hold garbage rows)
+                    cstate[k] = valid & v[idc]
+                elif k in _PER_OBJECT_FIELDS:
+                    cstate[k] = v[idc]
+                else:
+                    cstate[k] = v
+            table_c = None if big_table is None else jnp.asarray(big_table)[idc]
+            bloom_c = None if big_bloom is None else jnp.asarray(big_bloom)[idc]
+            sizes_c = None if sizes is None else sizes[idc]
+            lx = jnp.searchsorted(ids, xs).astype(jnp.int32)
 
         def f(cs, xl):
             return jax_cache.step(
                 cspec, cs, xl, sizes=sizes_c, table=table_c, bloom_tab=bloom_c
             )
 
-        cstate, hits = jax.lax.scan(f, cstate, lx)
+        with jax.named_scope("repro.step"):
+            cstate, hits = jax.lax.scan(f, cstate, lx)
         # ---- scatter the compact lanes back (sentinel id N is out of bounds
         # for the dense (N,) arrays, so mode="drop" discards invalid lanes)
-        new_state = {}
-        for k, v in state.items():
-            if k == "in_cache" or k in _PER_OBJECT_FIELDS:
-                new_state[k] = v.at[ids].set(cstate[k], mode="drop")
-            else:
-                new_state[k] = cstate[k]
+        with jax.named_scope("repro.scatter"):
+            new_state = {}
+            for k, v in state.items():
+                if k == "in_cache" or k in _PER_OBJECT_FIELDS:
+                    new_state[k] = v.at[ids].set(cstate[k], mode="drop")
+                else:
+                    new_state[k] = cstate[k]
         if spec.kind == "plfua_dyn":
             # refresh periods are whole multiples of the chunk (config
             # invariant), so the only possible boundary is the chunk end
@@ -624,14 +640,16 @@ def _build_fast(cfg: StreamConfig, sizes):
                 new_state,
             )
         # ---- roster rebuild: residents ⊆ old roster ∪ chunk ids
-        r2 = jnp.sort(jnp.concatenate([roster, xs]))
-        dup2 = jnp.concatenate([jnp.zeros((1,), jnp.bool_), r2[1:] == r2[:-1]])
-        keep = (~dup2) & (r2 < N) & new_state["in_cache"][jnp.minimum(r2, N - 1)]
-        new_roster = jax.lax.slice_in_dim(jnp.sort(jnp.where(keep, r2, N)), 0, R)
+        with jax.named_scope("repro.roster"):
+            r2 = jnp.sort(jnp.concatenate([roster, xs]))
+            dup2 = jnp.concatenate([jnp.zeros((1,), jnp.bool_), r2[1:] == r2[:-1]])
+            keep = (~dup2) & (r2 < N) & new_state["in_cache"][jnp.minimum(r2, N - 1)]
+            new_roster = jax.lax.slice_in_dim(jnp.sort(jnp.where(keep, r2, N)), 0, R)
         new_carry = {
             "state": new_state,
             "roster": new_roster,
             "hits": carry["hits"] + hits.sum(dtype=jnp.int32),
+            "lanes_valid": carry["lanes_valid"] + valid.sum(dtype=jnp.int32),
             "t0": t0 + jnp.int32(G),
         }
         return new_carry, {
@@ -644,9 +662,10 @@ def _build_fast(cfg: StreamConfig, sizes):
         "state": jax_cache.init_state(spec),
         "roster": jnp.full((R,), N, jnp.int32),
         "hits": jnp.zeros((), jnp.int32),
+        "lanes_valid": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    return jax.jit(chunk_fn, donate_argnums=0), carry0
+    return jax.jit(fast_chunk, donate_argnums=0), carry0, M
 
 
 class FleetStream:
@@ -672,7 +691,7 @@ class FleetStream:
         else:
             self._groups, og = None, None
         if cfg.fast:
-            self._push_fn, self._carry = _build_fast(cfg, self._sizes)
+            self._push_fn, self._carry, self._lanes = _build_fast(cfg, self._sizes)
         elif cfg.topo.has_placement:
             self._push_fn, self._carry = _build_placed(
                 cfg, self._sizes, og, self._groups
@@ -686,47 +705,58 @@ class FleetStream:
             [[] for _ in cfg.topo.levels] if telemetry is not None else None
         )
         self._pressure = [[] for _ in cfg.topo.levels] if og is not None else None
-        self._route = jax.jit(
-            lambda tr: router_mod.route_device(
-                tr,
-                cfg.topo.n_edges,
-                cfg.topo.router,
-                session_len=cfg.topo.session_len,
+
+        def route_chunk(tr):
+            return router_mod.route_device(
+                tr, cfg.topo.n_edges, cfg.topo.router, session_len=cfg.topo.session_len
             )
-        )
+
+        self._route = jax.jit(route_chunk)
 
     def push(self, trace, assignment=None):
         """Run one chunk. ``trace`` must be ``(chunk_len,)``; ``assignment``
         is the per-request edge node (int32, same shape) — omit it to route
         on device, which requires a single edge or the id-pure ``"hash"``
-        edge router (position-keyed routers cannot be chunked)."""
+        edge router (position-keyed routers cannot be chunked).
+
+        In a profile the call is the host span ``repro:push``, holding
+        ``repro:route`` (the edge assignment), ``repro:dispatch`` (the chunk
+        program's dispatch) and ``repro:stitch`` (telemetry series kept)."""
+        with jax.profiler.TraceAnnotation("repro:push"):
+            return self._push(trace, assignment)
+
+    def _push(self, trace, assignment):
         G = self.cfg.chunk_len
         if trace.shape != (G,):
             raise ValueError(f"expected chunk of shape ({G},), got {trace.shape}")
         if self.cfg.fast:
-            self._carry, out = self._push_fn(self._carry, trace)
+            with jax.profiler.TraceAnnotation("repro:dispatch"):
+                self._carry, out = self._push_fn(self._carry, trace)
             self.chunks += 1
             return out
-        if assignment is None:
-            if self.cfg.topo.n_edges == 1:
-                assignment = jnp.zeros((G,), jnp.int32)
-            elif self.cfg.topo.router == "hash":
-                assignment = self._route(trace)
-            else:
-                raise ValueError(
-                    f"edge router {self.cfg.topo.router!r} keys on the trace "
-                    f"position; pass an explicit per-chunk assignment"
-                )
-        self._carry, out = self._push_fn(
-            self._carry, trace, jnp.asarray(assignment, jnp.int32)
-        )
+        with jax.profiler.TraceAnnotation("repro:route"):
+            if assignment is None:
+                if self.cfg.topo.n_edges == 1:
+                    assignment = jnp.zeros((G,), jnp.int32)
+                elif self.cfg.topo.router == "hash":
+                    assignment = self._route(trace)
+                else:
+                    raise ValueError(
+                        f"edge router {self.cfg.topo.router!r} keys on the trace "
+                        f"position; pass an explicit per-chunk assignment"
+                    )
+            assignment = jnp.asarray(assignment, jnp.int32)
+        with jax.profiler.TraceAnnotation("repro:dispatch"):
+            self._carry, out = self._push_fn(self._carry, trace, assignment)
         self.chunks += 1
-        if self._series is not None:
-            for l, s in enumerate(out["telemetry"]):
-                self._series[l].append(s)
-        if self._pressure is not None:
-            for l, p in enumerate(out["telemetry_pressure"]):
-                self._pressure[l].append(p)
+        if self._series is not None or self._pressure is not None:
+            with jax.profiler.TraceAnnotation("repro:stitch"):
+                if self._series is not None:
+                    for l, s in enumerate(out["telemetry"]):
+                        self._series[l].append(s)
+                if self._pressure is not None:
+                    for l, p in enumerate(out["telemetry_pressure"]):
+                        self._pressure[l].append(p)
         return out
 
     def block(self):
@@ -747,7 +777,12 @@ class FleetStream:
         """Roll the stream up. Counter semantics match the bounded engines
         exactly (``tier_counters`` / ``assemble_placed``); telemetry series
         are the per-chunk window series concatenated (bit-identical to the
-        bounded series over the concatenated trace)."""
+        bounded series over the concatenated trace). The host waits for the
+        device's counters inside the span ``repro:sync``."""
+        with jax.profiler.TraceAnnotation("repro:sync"):
+            return self._stats(elapsed_s)
+
+    def _stats(self, elapsed_s):
         cfg = self.cfg
         requests = self.chunks * cfg.chunk_len
         if cfg.fast:
@@ -770,6 +805,8 @@ class FleetStream:
                 origin_misses=requests - hits,
                 tiers=(tier,),
                 elapsed_s=elapsed_s,
+                lanes=self.chunks * self._lanes,
+                lanes_valid=int(carry["lanes_valid"]),
             )
         carry = self._carry
         origin = int(carry["origin"])

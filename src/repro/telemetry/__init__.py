@@ -7,8 +7,7 @@ Five pieces (see docs/observability.md):
   host-side oracle — including the PR 8 group axis (``n_groups``) that
   segments every metric by an id→group catalogue (tenant attribution).
 * :mod:`repro.telemetry.timing` — warmup + ``block_until_ready`` measurement
-  harness with the AOT compile/execute split, measured J/op, and an optional
-  ``profile_dir=`` ``jax.profiler`` trace capture.
+  harness with the AOT compile/execute split and measured J/op.
 * :mod:`repro.telemetry.latency` — per-tier service-time model resolving
   grouped fleet series into per-tenant serving-level histograms and
   discrete p50/p99 request latency.

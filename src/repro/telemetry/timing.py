@@ -74,7 +74,7 @@ def j_per_step(cpu_seconds: float, steps: int) -> float:
 
 def measure(
     fn, *args, steps: int, static=(), repeats: int = 3, warmup: int = 1,
-    profile_dir=None, make_args=None, **kwargs
+    make_args=None, **kwargs
 ) -> Timing:
     """Measure ``fn(*args, **kwargs)`` with compile/execute separation.
 
@@ -93,12 +93,6 @@ def measure(
     *before* the clock each repeat, with its outputs blocked on, so argument
     materialization never leaks into the timing. ``args`` then only shapes
     the trace/compile; the measured calls consume the thunk's buffers.
-
-    ``profile_dir``: when set, one extra (untimed) call runs inside
-    ``jax.profiler.trace(profile_dir)`` *after* the timed repeats, writing a
-    TensorBoard-loadable device trace next to the numbers it explains. The
-    capture never pollutes the timing — profiling overhead stays outside
-    the clock.
     """
     if steps < 1:
         raise ValueError(f"steps must be >= 1, got {steps}")
@@ -129,9 +123,6 @@ def measure(
         t0 = time.perf_counter()
         jax.block_until_ready(call(a))
         times.append(time.perf_counter() - t0)
-    if profile_dir is not None:
-        with jax.profiler.trace(str(profile_dir)):
-            jax.block_until_ready(call(prep()))
     return Timing(
         steps=int(steps),
         repeats=len(times),

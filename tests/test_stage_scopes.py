@@ -1,0 +1,146 @@
+"""Profile names of the engines: the ``repro.<stage>`` scopes the lowered
+programs carry, the module names of the stream's programs, the host spans of
+``FleetStream.push``, and the fast path's compact-lane counter.
+
+The scopes must change nothing but operation metadata: each program's
+optimized HLO is compared with a twin built with ``jax.named_scope`` patched
+to a null context, metadata and stack-frame tables stripped."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import fleet, workloads
+from repro.core import jax_cache
+from repro.core.jax_cache import PolicySpec
+from repro.fleet.stream import FleetStream, StreamConfig
+
+N, C, G = 400, 40, 16
+
+
+def _fast(kind="plfua_dyn"):
+    kw = {"hot_size": (2 * C,)}
+    if kind == "plfua_dyn":
+        kw["refresh"] = (2 * G,)
+    topo = fleet.tree(n_objects=N, widths=(1,), kinds=kind, capacities=C, **kw)
+    return FleetStream(StreamConfig(topo=topo, chunk_len=G, fast=True))
+
+
+def _level_major():
+    topo = fleet.tree(n_objects=N, widths=(2, 1), kinds=("lru", "plfua_dyn"),
+                      capacities=(C, 2 * C), refresh=(0, 2 * G), router="hash")
+    return FleetStream(StreamConfig(topo=topo, chunk_len=G))
+
+
+def _placed():
+    topo = fleet.tree(n_objects=N, widths=(2, 1), kinds=("lru", "plfu"),
+                      capacities=(C, 2 * C), placements=("lce", "lcd"), router="hash")
+    return FleetStream(StreamConfig(topo=topo, chunk_len=G))
+
+
+_CHUNK = jax.ShapeDtypeStruct((G,), jnp.int32)
+_BATCH = jax.ShapeDtypeStruct((3, 64), jnp.int32)
+_PLFUA = PolicySpec("plfua", N, C, hot_size=2 * C)
+
+
+def _lowered(program):
+    """The lowered program of ``program``, built afresh (so that a patched
+    ``jax.named_scope`` takes effect)."""
+    jax.clear_caches()
+    if program == "simulate_batch":
+        return jax_cache.simulate_batch.lower(_PLFUA, _BATCH)
+    fs = {"fast_chunk": _fast, "level_major_chunk": _level_major,
+          "placed_chunk": _placed}[program]()
+    if program == "fast_chunk":
+        return fs._push_fn.lower(fs._carry, _CHUNK)
+    return fs._push_fn.lower(fs._carry, _CHUNK, _CHUNK)
+
+
+_SCOPES = {
+    "fast_chunk": ("step", "victim", "select", "lanes", "scatter", "roster", "refresh"),
+    "level_major_chunk": ("step", "victim", "refresh", "level0", "level1"),
+    "placed_chunk": ("step", "victim", "probe", "level0", "level1"),
+    "simulate_batch": ("step", "victim"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPES))
+def test_lowered_programs_carry_stage_scopes(program):
+    lowered = _lowered(program)
+    found = set(re.findall(r"\brepro\.([A-Za-z]\w*)", lowered.as_text(debug_info=True)))
+    assert set(_SCOPES[program]) <= found, sorted(found)
+    # in the compiled program's operation names (what a profile shows) the
+    # victim search lies inside the per-request scan
+    assert re.search(r'op_name="[^"]*repro\.step[^"]*/repro\.victim', lowered.compile().as_text())
+
+
+def _program_text(compiled_text: str) -> list:
+    """Optimized HLO without operation metadata and stack-frame tables, its
+    instructions and computations renamed in the order they are defined
+    (the name uniquer may number them differently)."""
+    text = re.sub(r",? metadata=\{[^{}]*\}", "", compiled_text)
+    table = re.compile(r"(FileNames|FunctionNames|FileLocations|StackFrames)$|\d+ ")
+    lines = [line for line in text.splitlines() if not table.match(line)]
+    names: dict = {}
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT |ENTRY )?%([\w.\-]+) [=(]", line)
+        if m:
+            names.setdefault(m.group(1), f"%v{len(names)}")
+    return [re.sub(r"%([\w.\-]+)", lambda m: names.get(m.group(1), m.group(0)), line)
+            for line in lines]
+
+
+@pytest.mark.parametrize("program", ["fast_chunk", "level_major_chunk", "simulate_batch"])
+def test_scopes_leave_the_optimized_program_unchanged(program, monkeypatch):
+    scoped = _lowered(program).compile().as_text()
+    assert "repro.step" in scoped
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = _lowered(program).compile().as_text()
+    assert "repro." not in plain
+    assert _program_text(scoped) == _program_text(plain)
+
+
+def test_stream_programs_have_distinct_module_names():
+    assert _lowered("fast_chunk").as_text().startswith("module @jit_fast_chunk")
+    assert _lowered("level_major_chunk").as_text().startswith("module @jit_level_major_chunk")
+    assert _lowered("placed_chunk").as_text().startswith("module @jit_placed_chunk")
+    fs = _level_major()
+    assert fs._route.lower(_CHUNK).as_text().startswith("module @jit_route_chunk")
+
+
+def _valid_lanes(kind, residents, key, xs, P):
+    """Distinct real ids among the P lexicographically smallest (key, id)
+    residents and the chunk's ids: the fast path's valid lanes."""
+    cand = sorted(residents, key=lambda r: (key[r], r))[:P]
+    return len(set(cand) | set(xs.tolist()))
+
+
+@pytest.mark.parametrize("kind", ["plfua", "lru"])
+def test_lanes_valid_matches_a_numpy_recount(kind):
+    fs = _fast(kind)
+    P = min(2 * G, C + G)  # C > 2G here, so the prefix cuts the residents
+    assert C > 2 * G
+    trace = workloads.make_traces("stationary", N, 1, 12 * G, seed=3)[0].astype(np.int32)
+    want = 0
+    for c in range(12):
+        state = fs.states()[0]
+        residents = np.flatnonzero(np.asarray(state["in_cache"]))
+        key = np.asarray(state["last"] if kind == "lru" else state["freq"])
+        xs = trace[c * G:(c + 1) * G]
+        want += _valid_lanes(kind, residents, key, xs, P)
+        fs.push(jnp.asarray(xs))
+    st = fs.stats()
+    assert st.lanes == 12 * (P + G)
+    assert st.lanes_valid == want
+    assert 0 < st.lanes_valid < st.lanes
+
+
+def test_lanes_are_none_off_the_fast_path():
+    fs = _level_major()
+    fs.push(jnp.arange(G, dtype=jnp.int32))
+    st = fs.stats()
+    assert st.lanes is None and st.lanes_valid is None
+

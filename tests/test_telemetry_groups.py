@@ -456,21 +456,3 @@ def test_grouped_spec_validation():
     _, pol = _pair("lru")
     with pytest.raises(ValueError):
         oracle.windowed_reference(pol, np.zeros(8, np.int32), 4, groups=GROUPS)
-
-
-# ------------------------------------------------------------ profiler capture
-def test_measure_profile_dir(tmp_path):
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def f(x):
-        return (x * 2).sum()
-
-    prof = tmp_path / "trace"
-    tr = telemetry.measure(
-        f, jnp.arange(64.0), steps=64, repeats=1, profile_dir=prof
-    )
-    assert tr.execute_s > 0
-    written = [p for p in prof.rglob("*") if p.is_file()]
-    assert written, "profiler trace directory is empty"
