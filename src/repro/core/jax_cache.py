@@ -23,6 +23,19 @@ object-count mode. The ``gdsf`` kind (GreedyDual-Size-Frequency) scores
 ``L + (freq << GDSF_SHIFT) // size`` with the global aging credit ``L``
 ratcheted to each evicted victim's score — all int32, so the Python
 reference, this scan, and the Pallas kernel agree bit for bit.
+
+Static ``plfua`` bounds its metadata by its admission: an id ``x >= H``
+(``H = effective_hot``) is never admitted, so it never hits, is never
+inserted or evicted, and its ``freq`` is never touched — its slot stays
+``(False, 0)`` for the whole run. :func:`simulate` (and through it
+:func:`simulate_batch`) therefore scans a spec of ``H + 1`` objects on the
+trace clamped to ``min(x, H)``: slot ``H`` stands for every non-admissible
+id and, with ``hot[H]`` false, is never admitted either. The final state is
+padded back to ``n_objects``, so hits, state, :func:`eviction_count` and
+:func:`metadata_entries` are those of the dense scan, bit for bit. Dense
+still: the telemetry path (:func:`instrumented_scan`), ``plfua_dyn``
+(its hot set moves), and every direct caller of :func:`step` (the streaming
+fast path, the fleet engines, :func:`run_chunk`).
 """
 from __future__ import annotations
 
@@ -848,6 +861,41 @@ def group_scatter_arrays(telemetry, groups, trace):
     return og, g[trace.astype(jnp.int32)]
 
 
+def _prefix_spec(spec: PolicySpec) -> PolicySpec | None:
+    """The (H + 1)-object spec static plfua's bounded scan runs on, or
+    ``None`` where the scan stays dense (another kind, or ``H >= N``)."""
+    h = spec.effective_hot
+    if spec.kind != "plfua" or h >= spec.n_objects:
+        return None
+    return dataclasses.replace(spec, n_objects=h + 1, hot_size=h)
+
+
+def _prefix_scan(spec: PolicySpec, small: PolicySpec, trace, sizes):
+    """:func:`simulate`'s scan on the admissible prefix (see its docstring
+    for why it is exact): clamp the trace to ``min(x, H)``, scan ``step`` on
+    ``small = _prefix_spec(spec)``, then pad ``in_cache``/``freq`` back with
+    zeros for ids ``H..N-1`` and rebuild ``hot``. The clamp and the pad-back
+    carry the profile scope ``repro.prefix``."""
+    h = small.hot_size
+    with jax.named_scope("repro.prefix"):
+        trace = jnp.minimum(trace.astype(jnp.int32), h)
+        if sizes is not None:
+            sizes = sizes[: h + 1]
+    with jax.named_scope("repro.step"):
+        state, hits = jax.lax.scan(
+            lambda s, x: step(small, s, x, sizes=sizes), init_state(small), trace
+        )
+    with jax.named_scope("repro.prefix"):
+        pad = (0, spec.n_objects - h)
+        state = {
+            **state,
+            "in_cache": jnp.pad(state["in_cache"][:h], pad),
+            "freq": jnp.pad(state["freq"][:h], pad),
+            "hot": jnp.arange(spec.n_objects, dtype=jnp.int32) < h,
+        }
+    return hits, state
+
+
 @functools.partial(jax.jit, static_argnums=(0, 2))
 def simulate(
     spec: PolicySpec, trace: jax.Array, telemetry=None, sizes=None, groups=None
@@ -859,10 +907,24 @@ def simulate(
     ``sizes`` is the per-object byte-size array (``None`` = unit sizes),
     consulted when ``spec.size_aware``; ``groups`` the per-object int32
     group catalogue consulted when ``telemetry.n_groups > 0`` (the series
-    gains a group axis: [n_windows, n_groups, N_METRICS])."""
-    state = init_state(spec)
+    gains a group axis: [n_windows, n_groups, N_METRICS]).
+
+    Static ``plfua`` with ``effective_hot = H < n_objects`` and no telemetry
+    scans the admissible prefix alone (:func:`_prefix_scan`): ids ``>= H``
+    are never admitted, so they never hit, are never inserted or evicted,
+    and their ``freq`` is never touched (``touch = hit | admitted`` is
+    false) — their slots stay ``(False, 0)`` for the whole run. The scan
+    runs on ``n_objects = H + 1`` with the trace clamped to ``min(x, H)``:
+    slot ``H`` stands for every such id and, with ``hot[H]`` false, is never
+    admitted either. The final state is padded back to ``n_objects``, so
+    hits and state are bit-identical to the dense scan. Every other kind,
+    ``hot_size >= n_objects``, and the telemetry path run dense."""
     if sizes is not None:
         sizes = jnp.asarray(sizes, jnp.int32)
+    small = None if telemetry is not None else _prefix_spec(spec)
+    if small is not None:
+        return _prefix_scan(spec, small, trace, sizes)
+    state = init_state(spec)
     if telemetry is None:
         with jax.named_scope("repro.step"):
             if spec.kind == "plfua_dyn":

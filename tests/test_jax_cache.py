@@ -1,4 +1,8 @@
 """JAX simulator must match the Python reference decision-for-decision."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -85,3 +89,119 @@ def test_chr_improves_lfu_to_plfu_to_plfua_smallN():
         out[kind] = hits.mean()
     assert out["plfu"] > out["lfu"]
     assert out["plfua"] >= out["plfu"] - 0.005
+
+
+# Static plfua scans the admissible prefix: (H + 1) slots, the trace clamped
+# to min(x, H), the state padded back to n_objects. Each case is checked
+# against the dense scan of `step` on the full spec and against the plain
+# reference, sample by sample and through the vmapped batch.
+PREFIX_CASES = {
+    # name: (n_objects, capacity, hot_size, capacity_bytes, n_samples)
+    "hot_below_n": (64, 5, 12, 0, 4),
+    "hot_at_least_n": (16, 5, 20, 0, 4),
+    "default_hot": (64, 5, 0, 0, 4),
+    "bytes": (64, 5, 12, 12, 4),
+    "zipf_12_samples": (1000, 20, 40, 0, 12),
+}
+
+
+def _dense_simulate(spec, trace, sizes=None):
+    """The dense program: `lax.scan` of `step` over the full (N,) state."""
+    sizes = None if sizes is None else jnp.asarray(sizes, jnp.int32)
+    state, hits = jax.lax.scan(
+        lambda s, x: jax_cache.step(spec, s, x, sizes=sizes),
+        jax_cache.init_state(spec),
+        jnp.asarray(trace, jnp.int32),
+    )
+    return hits, state
+
+
+def _prefix_traces(name, n, h, n_samples):
+    if name == "zipf_12_samples":
+        return zipf.sample_traces(n, n_samples=n_samples, trace_len=600, seed=11)
+    rng = np.random.default_rng(sum(map(ord, name)))
+    # half the requests fall in the hot prefix so hits and evictions happen;
+    # the ids on both sides of the boundary and the last id recur
+    traces = np.where(
+        rng.random((n_samples, 400)) < 0.5,
+        rng.integers(0, h, (n_samples, 400)),
+        rng.integers(0, n, (n_samples, 400)),
+    )
+    traces[:, ::7] = h - 1
+    traces[:, 3::11] = min(h, n - 1)
+    traces[:, 5::13] = n - 1
+    return traces.astype(np.int32)
+
+
+@pytest.mark.parametrize("name", list(PREFIX_CASES))
+def test_plfua_prefix_scan_exact(name):
+    n, cap, hot, cap_b, n_samples = PREFIX_CASES[name]
+    spec = jax_cache.PolicySpec(
+        "plfua", n_objects=n, capacity=cap, hot_size=hot, capacity_bytes=cap_b
+    )
+    h = spec.effective_hot
+    sizes = np.random.default_rng(3).integers(1, 5, n).astype(np.int32) if cap_b else None
+    traces = _prefix_traces(name, n, h, n_samples)
+    dense = jax.jit(functools.partial(_dense_simulate, spec))
+    batched = np.asarray(jax_cache.simulate_batch(spec, traces, None, sizes))
+    for s, trace in enumerate(traces):
+        hits, state = jax_cache.simulate(spec, trace, None, sizes)
+        want_hits, want_state = dense(trace, sizes)
+        np.testing.assert_array_equal(np.asarray(hits), np.asarray(want_hits))
+        np.testing.assert_array_equal(batched[s], np.asarray(want_hits))
+        assert sorted(state) == sorted(want_state)
+        for key in state:
+            np.testing.assert_array_equal(np.asarray(state[key]), np.asarray(want_state[key]))
+
+        pol = policies.make_policy(
+            "plfua", cap, hot=range(h), sizes=sizes, capacity_bytes=cap_b
+        )
+        ref_hits = np.array([pol.request(int(x)) for x in trace])
+        np.testing.assert_array_equal(np.asarray(hits), ref_hits)
+        plfu = pol._plfu
+        ref_freq = np.zeros(n, np.int32)
+        for obj, f in {**plfu._parked, **plfu._freq}.items():
+            ref_freq[obj] = f
+        np.testing.assert_array_equal(np.asarray(state["freq"]), ref_freq)
+        np.testing.assert_array_equal(
+            np.asarray(state["in_cache"]), [pol.contains(i) for i in range(n)]
+        )
+        np.testing.assert_array_equal(np.asarray(state["hot"]), np.arange(n) < h)
+        assert int(state["count"]) == len(plfu._freq)
+        assert jax_cache.eviction_count(spec, hits, trace, state) == pol.evictions
+        assert int(jax_cache.metadata_entries(spec, state)) == pol.metadata_entries
+
+
+def _scan_rows(jaxpr):
+    """Shapes of the rank-2 carries of every scan in a jaxpr, sub-jaxprs
+    (jit, vmap, nested scans) included."""
+    rows = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            k, c = eqn.params["num_consts"], eqn.params["num_carry"]
+            rows |= {v.aval.shape for v in eqn.invars[k:k + c] if v.aval.ndim == 2}
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    rows |= _scan_rows(sub)
+    return rows
+
+
+@pytest.mark.parametrize(
+    "kind,hot,compact",
+    [("plfua", 8, True), ("plfua", 0, True), ("plfua", 64, False),
+     ("plfu", 0, False), ("plfua_dyn", 8, False)],
+)
+def test_plfua_prefix_scan_engages(kind, hot, compact):
+    n, cap, S = 64, 3, 12
+    spec = jax_cache.PolicySpec(kind, n_objects=n, capacity=cap, hot_size=hot)
+    traces = jnp.zeros((S, 50), jnp.int32)
+    rows = _scan_rows(jax.make_jaxpr(
+        lambda tr: jax_cache.simulate_batch(spec, tr))(traces).jaxpr)
+    h1 = spec.effective_hot + 1
+    assert rows, "no rank-2 scan carry found"
+    if compact:
+        assert rows == {(S, h1)}
+    else:
+        assert (S, n) in rows and (S, h1) not in rows

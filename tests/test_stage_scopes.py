@@ -63,7 +63,7 @@ _SCOPES = {
     "fast_chunk": ("step", "victim", "select", "lanes", "scatter", "roster", "refresh"),
     "level_major_chunk": ("step", "victim", "refresh", "level0", "level1"),
     "placed_chunk": ("step", "victim", "probe", "level0", "level1"),
-    "simulate_batch": ("step", "victim"),
+    "simulate_batch": ("step", "victim", "prefix"),
 }
 
 
