@@ -17,6 +17,11 @@ assignment array:
 
 ROUTER_MODES lists the valid names. ``route`` returns an int32 ``(T,)`` (or
 ``(S, T)`` for batched traces) edge-assignment array.
+
+``sticky`` and ``round_robin`` key on the request's position in the stream.
+:func:`route_level` and :func:`route_device` take that position's offset
+``t0`` for the trace they are given, so a stream cut into chunks routes
+every chunk as the whole stream would: every router streams.
 """
 from __future__ import annotations
 
@@ -82,10 +87,17 @@ def route_level(
     *,
     session_len: int = 64,
     seed: int = 0,
+    t0=0,
     xp=np,
 ):
     """32-bit (lowbias32) router over one tier's ``n_nodes`` nodes, generic
     over ``xp`` (numpy or jax.numpy) with **bit-identical** partitions.
+
+    ``t0`` is the stream position of ``trace[..., 0]`` (an int32 scalar, may
+    be traced): request ``t`` sits at position ``t0 + t``, which ``sticky``
+    (session ``(t0 + t) // session_len``) and ``round_robin`` (node
+    ``(t0 + t) % n_nodes``) key on; ``hash`` ignores it. Routing a slice at
+    its offset equals routing the whole trace and slicing.
 
     This is the per-level routing primitive: non-edge tiers of a
     ``repro.fleet.Topology`` with a router kind (instead of the static
@@ -102,16 +114,16 @@ def route_level(
     T = trace.shape[-1]
     salt = xp.uint32(np.uint32(np.int64(seed) * _SEED_STRIDE & 0xFFFFFFFF))
     if mode == "round_robin":
-        assign = xp.broadcast_to(
-            xp.arange(T, dtype=xp.int32) % n_nodes, trace.shape
-        )
+        pos = xp.arange(T, dtype=xp.int32) + t0
+        assign = xp.broadcast_to(pos % n_nodes, trace.shape)
     elif mode == "hash":
         h = _mix32(trace.astype(xp.uint32) + salt, xp)
         assign = h % xp.uint32(n_nodes)
     elif mode == "sticky":
         if session_len < 1:
             raise ValueError(f"session_len must be >= 1, got {session_len}")
-        block = (xp.arange(T, dtype=xp.int32) // session_len).astype(xp.uint32)
+        pos = xp.arange(T, dtype=xp.int32) + t0
+        block = (pos // session_len).astype(xp.uint32)
         assign = xp.broadcast_to(
             _mix32(block + salt, xp) % xp.uint32(n_nodes), trace.shape
         )
@@ -160,10 +172,12 @@ def route_device(
     *,
     session_len: int = 64,
     seed: int = 0,
+    t0=0,
 ):
     """jnp analogue of :func:`route`, usable *inside* jit (the fleet's
     on-device trace-generation path routes freshly synthesized chunks without
-    a host round-trip).
+    a host round-trip). ``t0`` is the stream position of the trace's first
+    request, as in :func:`route_level`.
 
     Hash/sticky use the shared 32-bit lowbias mixer via :func:`route_level`
     (JAX runs with x64 off, so the host router's 64-bit avalanche is
@@ -174,5 +188,5 @@ def route_device(
     import jax.numpy as jnp
 
     return route_level(
-        trace, n_edges, mode, session_len=session_len, seed=seed, xp=jnp
+        trace, n_edges, mode, session_len=session_len, seed=seed, t0=t0, xp=jnp
     )

@@ -141,13 +141,16 @@ def tier_counters(spec: PolicySpec, hits, active, trace, state, sizes=None):
     return out
 
 
-def level_assignments(topo: Topology, trace: jax.Array, assignment: jax.Array) -> list[jax.Array]:
+def level_assignments(
+    topo: Topology, trace: jax.Array, assignment: jax.Array, t0=0
+) -> list[jax.Array]:
     """Per-level node assignment, one (T,) int32 per level: the edge
     assignment pushed up the parent tree for ``"tree"`` levels (parent maps
     are static tuples, folded into the jit as constants), or the level's own
-    router for routed tiers — the jnp instantiation of the xp-generic
+    router for routed tiers at stream position ``t0`` (a stream chunk's
+    traced offset) — the jnp instantiation of the xp-generic
     :func:`repro.fleet.topology.level_assignments` the oracle replays."""
-    return topo_mod.level_assignments(topo, trace, assignment, xp=jnp)
+    return topo_mod.level_assignments(topo, trace, assignment, xp=jnp, t0=t0)
 
 
 def stack_level_state(specs: tuple[PolicySpec, ...]):

@@ -81,13 +81,6 @@ __all__ = [
 #: LRU positions), byte mode is out (one insert can evict many victims).
 FAST_KINDS = ("lru", "lfu", "plfu", "plfua", "plfua_dyn", "gdsf", "tinylfu")
 
-#: routers usable above the edge in a stream: a pure function of the request
-#: id ("hash") or of the lower level's assignment ("tree"). "sticky" and
-#: "round_robin" key on the trace *position*, which a chunked stream resets
-#: every push — they would silently diverge from the bounded engine.
-_STREAM_ROUTERS = ("tree", "hash")
-
-
 @dataclasses.dataclass(frozen=True)
 class StreamConfig:
     """Static streaming-run configuration (hashable; the jit key).
@@ -108,14 +101,6 @@ class StreamConfig:
     def __post_init__(self):
         if self.chunk_len < 1:
             raise ValueError(f"chunk_len must be >= 1, got {self.chunk_len}")
-        for mode in self.topo.routers[1:]:
-            if mode not in _STREAM_ROUTERS:
-                raise ValueError(
-                    f"streaming upper levels need a position-independent "
-                    f"router {_STREAM_ROUTERS}, got {mode!r} (its assignment "
-                    f"depends on the trace position, which a chunked stream "
-                    f"resets every push)"
-                )
         if self.telemetry is not None and self.chunk_len % self.telemetry.window:
             raise ValueError(
                 f"telemetry window ({self.telemetry.window}) must divide "
@@ -155,10 +140,15 @@ class StreamStats:
     reduced dict its carry can derive (requests/hits/count[, inserts]).
     ``telemetry``/``telemetry_pressure`` are the stitched per-level series,
     shaped exactly like ``simulate_fleet``'s on the concatenated trace.
-    ``lanes`` is the fast path's compact lanes stepped (chunks x ``P +
-    chunk_len``) and ``lanes_valid`` those holding a real object (an int32
-    device counter, like ``hits``, exact below 2**31); both are ``None`` off
-    the fast path."""
+    ``lanes`` is the lanes the engine stepped and ``lanes_valid`` those that
+    held a real request, so their ratio is the share of stepped work that
+    served one. Fast path: compact lanes, chunks x ``P + chunk_len``, and
+    those holding a real object (an int32 device counter, like ``hits``,
+    exact below 2**31). Level-major engine: masked node-steps, chunks x
+    ``chunk_len`` x the tree's node count, and those whose node held an
+    active request (the tiers' ``requests`` summed). Placed engine: one
+    gathered node-step per level and position, chunks x ``chunk_len`` x
+    levels, and the same active count."""
 
     requests: int
     chunks: int
@@ -314,7 +304,8 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
     def level_major_chunk(carry, trace, assignment):
         t0 = carry["t0"]
         trace = trace.astype(jnp.int32)
-        assigns = sim_mod.level_assignments(topo, trace, assignment)
+        with jax.named_scope("repro.route"):
+            assigns = sim_mod.level_assignments(topo, trace, assignment, t0=t0)
         groups_t = None if groups is None else groups[trace]
         sz_t = None if sizes is None else jnp.take(sizes, trace, axis=-1)
         demand = jnp.ones((G,), jnp.bool_)
@@ -394,7 +385,9 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
         "origin": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    return jax.jit(level_major_chunk, donate_argnums=0), carry0
+    # every node masked-steps through every position
+    lanes = G * topo.n_nodes
+    return jax.jit(level_major_chunk, donate_argnums=0), carry0, lanes
 
 
 # ------------------------------------------------------------ placed chunks
@@ -418,7 +411,8 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
     def placed_chunk(carry, trace, assignment):
         t0 = carry["t0"]
         trace = trace.astype(jnp.int32)
-        assigns = sim_mod.level_assignments(topo, trace, assignment)
+        with jax.named_scope("repro.route"):
+            assigns = sim_mod.level_assignments(topo, trace, assignment, t0=t0)
         groups_t = None if groups is None else groups[trace]
         sz_t = None if sizes is None else jnp.take(sizes, trace, axis=-1)
         t_arr = t0 + jnp.arange(G, dtype=jnp.int32)
@@ -561,7 +555,9 @@ def _build_placed(cfg: StreamConfig, sizes, og, groups):
         "origin": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    return jax.jit(placed_chunk, donate_argnums=0), carry0
+    # time-major: one gathered node-step per level and position
+    lanes = G * topo.n_levels
+    return jax.jit(placed_chunk, donate_argnums=0), carry0, lanes
 
 
 # --------------------------------------------------- fast compact-lane path
@@ -693,11 +689,11 @@ class FleetStream:
         if cfg.fast:
             self._push_fn, self._carry, self._lanes = _build_fast(cfg, self._sizes)
         elif cfg.topo.has_placement:
-            self._push_fn, self._carry = _build_placed(
+            self._push_fn, self._carry, self._lanes = _build_placed(
                 cfg, self._sizes, og, self._groups
             )
         else:
-            self._push_fn, self._carry = _build_level_major(
+            self._push_fn, self._carry, self._lanes = _build_level_major(
                 cfg, self._sizes, og, self._groups
             )
         self.chunks = 0
@@ -706,18 +702,23 @@ class FleetStream:
         )
         self._pressure = [[] for _ in cfg.topo.levels] if og is not None else None
 
-        def route_chunk(tr):
-            return router_mod.route_device(
-                tr, cfg.topo.n_edges, cfg.topo.router, session_len=cfg.topo.session_len
-            )
+        def route_chunk(tr, t0):
+            with jax.named_scope("repro.route"):
+                return router_mod.route_device(
+                    tr, cfg.topo.n_edges, cfg.topo.router,
+                    session_len=cfg.topo.session_len, t0=t0,
+                )
 
         self._route = jax.jit(route_chunk)
 
     def push(self, trace, assignment=None):
         """Run one chunk. ``trace`` must be ``(chunk_len,)``; ``assignment``
         is the per-request edge node (int32, same shape) — omit it to route
-        on device, which requires a single edge or the id-pure ``"hash"``
-        edge router (position-keyed routers cannot be chunked).
+        on device with the topology's edge router, at the chunk's stream
+        position (``chunks x chunk_len``, a traced int32, so positions wrap
+        past 2**31 requests as the carry's do): any router, ``sticky`` and
+        ``round_robin`` included, routes a chunked stream as it would the
+        whole trace.
 
         In a profile the call is the host span ``repro:push``, holding
         ``repro:route`` (the edge assignment), ``repro:dispatch`` (the chunk
@@ -738,13 +739,9 @@ class FleetStream:
             if assignment is None:
                 if self.cfg.topo.n_edges == 1:
                     assignment = jnp.zeros((G,), jnp.int32)
-                elif self.cfg.topo.router == "hash":
-                    assignment = self._route(trace)
                 else:
-                    raise ValueError(
-                        f"edge router {self.cfg.topo.router!r} keys on the trace "
-                        f"position; pass an explicit per-chunk assignment"
-                    )
+                    t0 = np.int64(self.chunks * G).astype(np.int32)
+                    assignment = self._route(trace, t0)
             assignment = jnp.asarray(assignment, jnp.int32)
         with jax.profiler.TraceAnnotation("repro:dispatch"):
             self._carry, out = self._push_fn(self._carry, trace, assignment)
@@ -843,6 +840,8 @@ class FleetStream:
             elapsed_s=elapsed_s,
             telemetry=telemetry,
             telemetry_pressure=pressure,
+            lanes=self.chunks * self._lanes,
+            lanes_valid=sum(int(np.asarray(t["requests"]).sum()) for t in tiers),
         )
 
 

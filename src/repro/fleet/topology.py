@@ -228,12 +228,13 @@ class Topology:
         )
 
 
-def level_assignments(topo: Topology, trace, assignment, xp=np):
+def level_assignments(topo: Topology, trace, assignment, xp=np, t0=0):
     """Per-level node assignment of every request: one (T,) int array per
     level. Level 0 is the given edge ``assignment``; an upper level either
     follows the static parent map (``"tree"``, assignment pushed up) or
     routes the request stream itself with its own router kind
-    (:func:`repro.cdn.router.route_level`, seeded by the level index).
+    (:func:`repro.cdn.router.route_level`, seeded by the level index, at
+    the stream position ``t0`` of ``trace[0]``).
 
     ``xp``-generic (numpy or jax.numpy) with bit-identical results — the
     jitted simulator and the pure-Python oracle both call this, which is
@@ -249,7 +250,7 @@ def level_assignments(topo: Topology, trace, assignment, xp=np):
             outs.append(
                 router_mod.route_level(
                     xp.asarray(trace), len(topo.levels[l + 1]), mode,
-                    session_len=topo.session_len, seed=l + 1, xp=xp,
+                    session_len=topo.session_len, seed=l + 1, t0=t0, xp=xp,
                 )
             )
     return outs

@@ -173,6 +173,31 @@ def test_round_robin_router_balances_exactly():
     assert counts.max() - counts.min() <= 1
 
 
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("mode", router_mod.ROUTER_MODES)
+def test_route_level_at_an_offset_equals_the_whole_trace_sliced(mode, xp_name):
+    """A slice routed at its stream offset ``t0`` is the whole trace routed
+    and sliced, for every router (sessions straddle the cuts: 16 divides
+    none of them), numpy and jnp bit-identical, the jnp offset traced."""
+    import jax
+    import jax.numpy as jnp
+
+    trace = workloads.make_traces("stationary", N, 1, 1_000, seed=2)[0].astype(np.int32)
+    kw = dict(session_len=16, seed=2)
+    whole = router_mod.route_level(trace, 5, mode, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(router_mod.route_level(jnp.asarray(trace), 5, mode, xp=jnp, **kw)), whole
+    )
+    routed = jax.jit(lambda tr, t0: router_mod.route_device(tr, 5, mode, t0=t0, **kw))
+    for lo, hi in [(0, 50), (37, 137), (450, 1_000), (999, 1_000)]:
+        if xp_name == "numpy":
+            part = router_mod.route_level(trace[lo:hi], 5, mode, t0=lo, **kw)
+        else:
+            part = np.asarray(routed(jnp.asarray(trace[lo:hi]), jnp.int32(lo)))
+        assert part.dtype == np.int32
+        np.testing.assert_array_equal(part, whole[lo:hi], err_msg=f"{mode} [{lo}:{hi}]")
+
+
 def test_hash_router_balances_approximately():
     trace = np.arange(10_000, dtype=np.int64) % 997  # near-uniform object mix
     assign = router_mod.route(trace, 8, "hash")

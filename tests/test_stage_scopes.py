@@ -61,8 +61,8 @@ def _lowered(program):
 
 _SCOPES = {
     "fast_chunk": ("step", "victim", "select", "lanes", "scatter", "roster", "refresh"),
-    "level_major_chunk": ("step", "victim", "refresh", "level0", "level1"),
-    "placed_chunk": ("step", "victim", "probe", "level0", "level1"),
+    "level_major_chunk": ("step", "victim", "refresh", "level0", "level1", "route"),
+    "placed_chunk": ("step", "victim", "probe", "level0", "level1", "route"),
     "simulate_batch": ("step", "victim", "prefix"),
 }
 
@@ -108,7 +108,9 @@ def test_stream_programs_have_distinct_module_names():
     assert _lowered("level_major_chunk").as_text().startswith("module @jit_level_major_chunk")
     assert _lowered("placed_chunk").as_text().startswith("module @jit_placed_chunk")
     fs = _level_major()
-    assert fs._route.lower(_CHUNK).as_text().startswith("module @jit_route_chunk")
+    route = fs._route.lower(_CHUNK, jax.ShapeDtypeStruct((), jnp.int32))
+    assert route.as_text().startswith("module @jit_route_chunk")
+    assert "repro.route" in route.as_text(debug_info=True)
 
 
 def _valid_lanes(kind, residents, key, xs, P):
@@ -139,8 +141,16 @@ def test_lanes_valid_matches_a_numpy_recount(kind):
 
 
 def test_lanes_are_none_off_the_fast_path():
-    fs = _level_major()
-    fs.push(jnp.arange(G, dtype=jnp.int32))
-    st = fs.stats()
-    assert st.lanes is None and st.lanes_valid is None
+    """Off the fast path the engines count node-steps: every node of the
+    level-major tree steps every position, the placed engine one node a
+    level; the valid ones are the active requests, the tiers' ``requests``."""
+    for fs, stepped in ((_level_major(), 3), (_placed(), 2)):
+        fs.push(jnp.arange(G, dtype=jnp.int32))
+        fs.push(jnp.arange(G, dtype=jnp.int32))
+        st = fs.stats()
+        assert st.lanes == 2 * G * stepped
+        # a cold pass of G distinct ids misses its edge and reaches the root;
+        # the second pass hits its edge (C > G)
+        assert st.lanes_valid == sum(int(np.asarray(t["requests"]).sum()) for t in st.tiers)
+        assert st.lanes_valid == 3 * G
 

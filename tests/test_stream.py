@@ -17,7 +17,10 @@ bench numbers (BENCH_PR10 ``fleet_stream`` group) legitimate measurements of
 * the fast compact-lane path against the dense flat simulator for every
   FAST_KIND (the candidate-prefix bound, tie-breaks included);
 * the double-buffered ``stream_fleet`` driver against a bounded run over
-  the same on-device-generated chunks.
+  the same on-device-generated chunks;
+* position-keyed routers (``sticky``, ``round_robin``) at every level,
+  routed on device from the absolute stream position, sessions straddling
+  chunk boundaries, against the bounded engine and the plain reference.
 """
 import numpy as np
 import pytest
@@ -26,7 +29,9 @@ import jax.numpy as jnp
 
 from repro import fleet, workloads
 from repro.core import jax_cache
+from repro.cdn import router
 from repro.core.jax_cache import PolicySpec
+from repro.fleet.reference import simulate_fleet_reference
 from repro.fleet.stream import FAST_KINDS, FleetStream, StreamConfig, stream_fleet
 from repro.telemetry import TelemetrySpec
 
@@ -100,18 +105,62 @@ def _assert_stream_matches(bounded, fs, hits_chunks, *, tel=False, ctx=""):
             )
 
 
+def _device_assignment(topo, trace):
+    """The whole trace's edge assignment as ``push`` routes it on device."""
+    return np.asarray(router.route_device(
+        jnp.asarray(trace), topo.n_edges, topo.router, session_len=topo.session_len
+    ))
+
+
+def _assert_routed_stream_matches(topo, trace, chunk_len):
+    """Push ``trace`` in chunks with no assignment; the stream equals the
+    bounded engine on the concatenation (per-level and per-node hits, tier
+    counters, states) and the plain reference (per-level hits), and its lane
+    counters count the engine's node-steps and the active ones."""
+    n = len(trace) // chunk_len
+    assignment = _device_assignment(topo, trace)
+    bounded = fleet.simulate_fleet(topo, jnp.asarray(trace), jnp.asarray(assignment))
+    ref = simulate_fleet_reference(topo, trace, assignment)
+    fs = FleetStream(StreamConfig(topo=topo, chunk_len=chunk_len))
+    outs = [fs.push(jnp.asarray(trace[c * chunk_len:(c + 1) * chunk_len])) for c in range(n)]
+    st = fs.stats()
+    reached = np.ones(len(trace), bool)
+    n_reached = 0
+    for l in range(topo.n_levels):
+        for key in ("hit", "node_hit"):
+            cat = np.concatenate([np.asarray(o[key][l]) for o in outs], axis=-1)
+            np.testing.assert_array_equal(cat, np.asarray(bounded[key][l]),
+                                          err_msg=f"{key} level {l}")
+        np.testing.assert_array_equal(np.asarray(bounded["hit"][l]), ref.level_hit[l],
+                                      err_msg=f"reference level {l}")
+        for k in bounded["tiers"][l]:
+            np.testing.assert_array_equal(np.asarray(bounded["tiers"][l][k]),
+                                          np.asarray(st.tiers[l][k]), err_msg=f"tiers[{l}][{k}]")
+        for k in bounded["states"][l]:
+            np.testing.assert_array_equal(np.asarray(bounded["states"][l][k]),
+                                          np.asarray(fs.states()[l][k]), err_msg=f"states[{l}][{k}]")
+        n_reached += int(reached.sum())
+        reached &= ~ref.level_hit[l]
+    assert st.origin_misses == int(reached.sum())
+    stepped = topo.n_levels if topo.has_placement else topo.n_nodes
+    assert st.lanes == n * chunk_len * stepped
+    assert st.lanes_valid == n_reached
+    return st
+
+
 # ----------------------------------------------------------- config contract
 def test_stream_config_validation():
     topo = _topo("lru")
     with pytest.raises(ValueError, match="chunk_len"):
         StreamConfig(topo=topo, chunk_len=0)
-    # position-keyed upper routers would diverge when the stream resets t
+    # position-keyed upper routers route from the absolute stream position,
+    # so a chunked stream equals the bounded engine on the whole trace
     sticky = fleet.tree(
         n_objects=N, widths=(3, 2, 1), kinds="lru", capacities=(5, 9, 13),
         routers=("hash", "sticky", "tree"),
     )
-    with pytest.raises(ValueError, match="position-independent"):
-        StreamConfig(topo=sticky, chunk_len=G)
+    trace = workloads.make_traces("stationary", N, 1, T, seed=21)[0]
+    _assert_routed_stream_matches(sticky, trace, G)
     # telemetry windows must tile the chunk so series stitch by concatenation
     with pytest.raises(ValueError, match="window"):
         StreamConfig(topo=topo, chunk_len=G, telemetry=TelemetrySpec(window=30))
@@ -138,15 +187,28 @@ def test_stream_push_contract():
     fs = FleetStream(StreamConfig(topo=topo, chunk_len=G))
     with pytest.raises(ValueError, match="shape"):
         fs.push(jnp.zeros((G + 1,), jnp.int32))
-    # sticky *edge* router is fine for the engine (assignment is an input),
-    # but cannot be synthesized on device — an explicit array is required
+    # a sticky *edge* router is routed on device at the chunk's stream
+    # position: pushing no assignment equals pushing the whole trace's
+    # assignment, sliced
     sticky_edge = fleet.tree(
         n_objects=N, widths=(3, 1), kinds="lru", capacities=(5, 13),
         router="sticky",
     )
-    fs = FleetStream(StreamConfig(topo=sticky_edge, chunk_len=G))
-    with pytest.raises(ValueError, match="assignment"):
-        fs.push(jnp.zeros((G,), jnp.int32))
+    trace = workloads.make_traces("stationary", N, 1, T, seed=22)[0]
+    assignment = _device_assignment(sticky_edge, trace)
+    routed = FleetStream(StreamConfig(topo=sticky_edge, chunk_len=G))
+    given = FleetStream(StreamConfig(topo=sticky_edge, chunk_len=G))
+    for c in range(K):
+        sl = slice(c * G, (c + 1) * G)
+        a = routed.push(jnp.asarray(trace[sl]))
+        b = given.push(jnp.asarray(trace[sl]), jnp.asarray(assignment[sl]))
+        for l in range(2):
+            np.testing.assert_array_equal(np.asarray(a["node_hit"][l]),
+                                          np.asarray(b["node_hit"][l]))
+    for l in range(2):
+        for k in routed.states()[l]:
+            np.testing.assert_array_equal(np.asarray(routed.states()[l][k]),
+                                          np.asarray(given.states()[l][k]))
 
 
 # --------------------------------------------- level-major engine, all kinds
@@ -331,3 +393,44 @@ def test_stream_fleet_double_buffered_generation():
     assert st.j_per_step is not None and st.j_per_step > 0
     with pytest.raises(ValueError, match="trace_len"):
         stream_fleet(StreamConfig(topo=topo, chunk_len=G + 1), dspec, 2)
+
+
+# ----------------------------------------------- position-keyed routing
+#: topologies whose routers key on the stream position at some level; the
+#: session length 16 divides neither G = 50 nor the chunk boundaries
+_ROUTED = {
+    "sticky_edge": dict(widths=(3, 1), kinds="lru", capacities=(5, 13), router="sticky"),
+    "sticky_upper": dict(widths=(3, 2, 1), kinds="lru", capacities=(5, 9, 13),
+                         routers=("hash", "sticky", "tree")),
+    "round_robin_upper": dict(widths=(3, 2, 1), kinds=("lru", "plfu", "lfu"),
+                              capacities=(5, 9, 13), routers=("sticky", "round_robin", "hash")),
+    "round_robin_edge": dict(widths=(4, 2), kinds=("plfu", "lru"), capacities=(5, 11),
+                             routers=("round_robin", "sticky")),
+    "placed": dict(widths=(3, 2), kinds=("lru", "plfu"), capacities=(5, 11),
+                   placements=("lce", "lcd"), routers=("sticky", "round_robin")),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ROUTED))
+def test_stream_position_keyed_routers_match_bounded(name):
+    topo = fleet.tree(n_objects=N, session_len=16, **_ROUTED[name])
+    assert topo.has_placement == (name == "placed")
+    trace = workloads.make_traces("churn", N, 1, T, seed=31)[0]
+    _assert_routed_stream_matches(topo, trace, G)
+
+
+def test_stream_edge8_origin4_fleet_matches_bounded():
+    """The photo-CDN shape at N = 3,000: 8 sticky PLFUA edges (rate 0.02,
+    hot set 2 x C) over 4 hash-partitioned PLFU origin nodes, sessions of 64
+    in chunks of 256, no assignment pushed."""
+    n = 3_000
+    topo = fleet.tree(
+        n_objects=n, widths=(8, 4), kinds=("plfua", "plfu"), capacities=(60, 150),
+        hot_size=(120, 0), routers=("sticky", "hash"), session_len=64,
+    )
+    trace = workloads.make_traces("stationary", n, 1, 6 * 256, seed=41)[0]
+    st = _assert_routed_stream_matches(topo, trace, 256)
+    # each request steps 12 nodes and is active at its edge and, on an edge
+    # miss, at one origin node
+    assert st.lanes == 6 * 256 * 12
+    assert 6 * 256 < st.lanes_valid < 2 * 6 * 256
