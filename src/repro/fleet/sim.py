@@ -7,9 +7,12 @@ Two engines share this module, selected statically per topology:
   nodes: node ``i`` at level ``l`` is *active* at trace position ``t`` iff
   the request routed to it (the edge assignment pushed up the parent tree)
   **and** no level below served it — i.e. each tier consumes exactly the
-  interleaved miss stream of its children, in true request order. State
-  updates freeze under a ``where`` when inactive, so the whole topology is
-  fixed-shape, jittable, and vmaps over trace samples.
+  interleaved miss stream of its children, in true request order. Each
+  node steps its own requests, compacted (:func:`masked_scan`): a level's
+  loop runs to its busiest node's load, not to the trace length, while
+  every shape stays fixed, so the whole topology is jittable and vmaps
+  over trace samples. plfua_dyn and instrumented levels keep the dense
+  scan over every position.
 
 * **Time-major** (any non-lce placement, :mod:`repro.fleet.placement`):
   cross-tier placement makes a tier's insert decision depend on *where the
@@ -69,17 +72,28 @@ def masked_scan(
     cap_bytes=None,
     og=None,
 ):
-    """Scan ``step`` over the trace, freezing state where ``active`` is False.
+    """Step one node through its own requests, the positions where
+    ``active`` is True, in stream order; returns ``(state, hits & active)``.
 
-    plfua_dyn routes through the chunked scan so its global-time hot-set
-    refresh fires at trace-position boundaries for every instance, active or
-    not (the reference oracle drives ``refresh_now`` on the same timer).
+    Each node steps its own requests, compacted: the active positions are
+    packed to the front, their ids gathered once, and ``step`` runs once a
+    request (a ``while`` loop with a traced trip count), the hit bits then
+    gathered back to their positions. ``step`` reads no position, so the
+    states and hits are those of a scan over every position that freezes
+    the state where the node is inactive. Under a level's ``vmap`` the loop
+    runs to the busiest node's load; a node that has finished keeps its
+    state through the loop's one batched select a step.
 
-    ``instrument`` (static) switches to the telemetry twin, which returns
-    ``(state, hits, events)`` with the per-step event series (identical
-    state/hit trajectory — asserted in tests/test_telemetry.py). ``sizes``/
-    ``cap_bytes`` are the byte-capacity inputs of ``jax_cache.step``; ``og``
-    the (n_objects, n_groups) group one-hot for group-segmented telemetry."""
+    plfua_dyn routes through the dense chunked scan so its global-time
+    hot-set refresh fires at trace-position boundaries for every instance,
+    active or not (the reference oracle drives ``refresh_now`` on the same
+    timer). ``instrument`` (static) switches to the dense telemetry twin,
+    which returns ``(state, hits, events)`` with the per-position event
+    series (identical state/hit trajectory — asserted in
+    tests/test_telemetry.py). :func:`compacts` says which scan a level gets.
+    ``sizes``/``cap_bytes`` are the byte-capacity inputs of
+    ``jax_cache.step``; ``og`` the (n_objects, n_groups) group one-hot for
+    group-segmented telemetry."""
     if instrument:
         return jax_cache.instrumented_scan(
             spec, state, trace, active, cap, sizes=sizes, cap_bytes=cap_bytes, og=og
@@ -89,13 +103,35 @@ def masked_scan(
             spec, state, trace, active, cap, sizes=sizes, cap_bytes=cap_bytes
         )
 
-    def f(s, inp):
-        x, a = inp
-        ns, hit = jax_cache.step(spec, s, x, cap, sizes=sizes, cap_bytes=cap_bytes)
-        ns = jax.tree_util.tree_map(lambda o, n: jnp.where(a, n, o), s, ns)
-        return ns, hit & a
+    T = trace.shape[0]
+    with jax.named_scope("repro.compact"):
+        # slot of each active position in the packed order; inactive
+        # positions scatter out of bounds and are dropped
+        slot = jnp.cumsum(active, dtype=jnp.int32) - 1
+        pos = jnp.zeros((T,), jnp.int32).at[jnp.where(active, slot, T)].set(
+            jnp.arange(T, dtype=jnp.int32), mode="drop"
+        )
+        ids = jnp.take(trace, pos)
+        n = active.sum(dtype=jnp.int32)
 
-    return jax.lax.scan(f, state, (trace, active))
+    def body(carry):
+        i, s, hits = carry
+        ns, hit = jax_cache.step(spec, s, ids[i], cap, sizes=sizes, cap_bytes=cap_bytes)
+        return i + 1, ns, hits.at[i].set(hit)
+
+    # zeros_like keeps ``active``'s sharding (varying under a shard_map)
+    _, state, hits = jax.lax.while_loop(
+        lambda carry: carry[0] < n, body, (jnp.int32(0), state, jnp.zeros_like(active))
+    )
+    with jax.named_scope("repro.compact"):
+        return state, jnp.take(hits, jnp.maximum(slot, 0)) & active
+
+
+def compacts(spec: PolicySpec, instrument: bool = False) -> bool:
+    """Whether :func:`masked_scan` steps a node through its own requests
+    only (True) or through every position of the trace (plfua_dyn's
+    global-time refresh, the telemetry twin's per-position series)."""
+    return not instrument and spec.kind != "plfua_dyn"
 
 
 def tier_counters(spec: PolicySpec, hits, active, trace, state, sizes=None):

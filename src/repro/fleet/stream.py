@@ -140,15 +140,21 @@ class StreamStats:
     reduced dict its carry can derive (requests/hits/count[, inserts]).
     ``telemetry``/``telemetry_pressure`` are the stitched per-level series,
     shaped exactly like ``simulate_fleet``'s on the concatenated trace.
-    ``lanes`` is the lanes the engine stepped and ``lanes_valid`` those that
-    held a real request, so their ratio is the share of stepped work that
-    served one. Fast path: compact lanes, chunks x ``P + chunk_len``, and
+    ``lanes`` is the engine's lane grid and ``lanes_valid`` the lanes that
+    held a real request; ``node_steps`` is what the engine's loops ran.
+    Fast path: compact lanes, chunks x ``P + chunk_len``, all stepped, and
     those holding a real object (an int32 device counter, like ``hits``,
-    exact below 2**31). Level-major engine: masked node-steps, chunks x
-    ``chunk_len`` x the tree's node count, and those whose node held an
-    active request (the tiers' ``requests`` summed). Placed engine: one
-    gathered node-step per level and position, chunks x ``chunk_len`` x
-    levels, and the same active count."""
+    exact below 2**31). Level-major engine: the dense node x position grid,
+    chunks x ``chunk_len`` x the tree's node count, and the node-steps
+    whose node held an active request (the tiers' ``requests`` summed);
+    each node steps its own requests compacted (``sim.masked_scan``), so a
+    level's loop runs ``K_l`` x its busiest node's load a chunk, or ``K_l``
+    x ``chunk_len`` for a level that keeps the dense scan (plfua_dyn,
+    telemetry) — ``node_steps`` sums them in an int32 device counter and
+    ``lanes_valid / node_steps`` is the share of stepped work that served a
+    request. Placed engine: one gathered node-step per level and position,
+    chunks x ``chunk_len`` x levels, all stepped, and the same active
+    count."""
 
     requests: int
     chunks: int
@@ -161,6 +167,7 @@ class StreamStats:
     telemetry_pressure: tuple | None = None
     lanes: int | None = None
     lanes_valid: int | None = None
+    node_steps: int | None = None
 
     @property
     def total_chr(self) -> float:
@@ -309,6 +316,7 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
         groups_t = None if groups is None else groups[trace]
         sz_t = None if sizes is None else jnp.take(sizes, trace, axis=-1)
         demand = jnp.ones((G,), jnp.bool_)
+        node_steps = carry["node_steps"]
         new_states, new_acc = [], []
         hit_lv, node_hit, series, pressure = [], [], [], []
         for l, specs in enumerate(topo.levels):
@@ -352,6 +360,12 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
                             )
                 else:
                     states_l, hits = out
+                if sim_mod.compacts(s0, instrument):
+                    # the level's loop runs to its busiest node's load
+                    loads = active.sum(-1, dtype=jnp.int32)
+                    node_steps = node_steps + jnp.int32(K) * loads.max()
+                else:
+                    node_steps = node_steps + jnp.int32(K * G)
                 new_states.append(states_l)
                 new_acc.append(
                     _accumulate_level(
@@ -366,6 +380,7 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
             "states": tuple(new_states),
             "acc": tuple(new_acc),
             "origin": carry["origin"] + demand.sum(dtype=jnp.int32),
+            "node_steps": node_steps,
             "t0": t0 + jnp.int32(G),
         }
         out = {
@@ -383,9 +398,10 @@ def _build_level_major(cfg: StreamConfig, sizes, og, groups):
         "states": tuple(sim_mod.stack_level_state(lvl) for lvl in topo.levels),
         "acc": _zero_acc(topo, sizes is not None),
         "origin": jnp.zeros((), jnp.int32),
+        "node_steps": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
-    # every node masked-steps through every position
+    # the dense grid: every node at every position of the chunk
     lanes = G * topo.n_nodes
     return jax.jit(level_major_chunk, donate_argnums=0), carry0, lanes
 
@@ -804,6 +820,7 @@ class FleetStream:
                 elapsed_s=elapsed_s,
                 lanes=self.chunks * self._lanes,
                 lanes_valid=int(carry["lanes_valid"]),
+                node_steps=self.chunks * self._lanes,
             )
         carry = self._carry
         origin = int(carry["origin"])
@@ -842,6 +859,11 @@ class FleetStream:
             telemetry_pressure=pressure,
             lanes=self.chunks * self._lanes,
             lanes_valid=sum(int(np.asarray(t["requests"]).sum()) for t in tiers),
+            node_steps=(
+                self.chunks * self._lanes
+                if cfg.topo.has_placement
+                else int(carry["node_steps"])
+            ),
         )
 
 

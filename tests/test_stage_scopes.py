@@ -138,6 +138,7 @@ def test_lanes_valid_matches_a_numpy_recount(kind):
     assert st.lanes == 12 * (P + G)
     assert st.lanes_valid == want
     assert 0 < st.lanes_valid < st.lanes
+    assert st.node_steps == st.lanes  # every compact lane is stepped
 
 
 def test_lanes_are_none_off_the_fast_path():
@@ -153,4 +154,9 @@ def test_lanes_are_none_off_the_fast_path():
         # the second pass hits its edge (C > G)
         assert st.lanes_valid == sum(int(np.asarray(t["requests"]).sum()) for t in st.tiers)
         assert st.lanes_valid == 3 * G
+        # the placed engine steps its whole grid; the level-major tree's
+        # plfua_dyn root keeps the dense scan, its lru edges are compacted
+        assert st.lanes_valid <= st.node_steps <= st.lanes
+        if stepped == 2:
+            assert st.node_steps == st.lanes
 

@@ -112,11 +112,32 @@ def _device_assignment(topo, trace):
     ))
 
 
+def _recount_node_steps(topo, trace, assignment, level_hit, chunk_len, telemetry=False):
+    """Sum over chunks and levels of what the level-major engine's loops
+    run: ``K_l`` x the busiest node's load at a compacted level, ``K_l`` x
+    ``chunk_len`` at a dense one (plfua_dyn, or any level with telemetry).
+    The loads come from the levels' assignments and the served levels."""
+    assigns = [np.asarray(a) for a in fleet.level_assignments(
+        topo, jnp.asarray(trace), jnp.asarray(assignment))]
+    reached = np.ones(len(trace), bool)
+    total = 0
+    for l, lvl in enumerate(topo.levels):
+        K = len(lvl)
+        dense = telemetry or lvl[0].kind == "plfua_dyn"
+        for c in range(len(trace) // chunk_len):
+            sl = slice(c * chunk_len, (c + 1) * chunk_len)
+            loads = np.bincount(assigns[l][sl][reached[sl]], minlength=K)
+            total += K * (chunk_len if dense else int(loads.max()))
+        reached &= ~np.asarray(level_hit[l])
+    return total
+
+
 def _assert_routed_stream_matches(topo, trace, chunk_len):
     """Push ``trace`` in chunks with no assignment; the stream equals the
     bounded engine on the concatenation (per-level and per-node hits, tier
-    counters, states) and the plain reference (per-level hits), and its lane
-    counters count the engine's node-steps and the active ones."""
+    counters, states) and the plain reference (per-level hits), its lane
+    counters count the dense node-step grid and the active node-steps, and
+    ``node_steps`` what the engine's loops ran."""
     n = len(trace) // chunk_len
     assignment = _device_assignment(topo, trace)
     bounded = fleet.simulate_fleet(topo, jnp.asarray(trace), jnp.asarray(assignment))
@@ -145,6 +166,13 @@ def _assert_routed_stream_matches(topo, trace, chunk_len):
     stepped = topo.n_levels if topo.has_placement else topo.n_nodes
     assert st.lanes == n * chunk_len * stepped
     assert st.lanes_valid == n_reached
+    if topo.has_placement:
+        assert st.node_steps == st.lanes
+    else:
+        assert st.node_steps == _recount_node_steps(
+            topo, trace, assignment, ref.level_hit, chunk_len
+        )
+        assert st.lanes_valid <= st.node_steps <= st.lanes
     return st
 
 
@@ -419,6 +447,60 @@ def test_stream_position_keyed_routers_match_bounded(name):
     _assert_routed_stream_matches(topo, trace, G)
 
 
+def test_stream_chunk_on_one_edge():
+    """Sessions as long as a chunk: every session of a chunk lands on one
+    edge, the others idle, so level 0's loop runs the whole chunk."""
+    topo = fleet.tree(n_objects=N, widths=(3, 2), kinds=("plfua", "lru"),
+                      capacities=(5, 11), hot_size=(10, 0), router="sticky",
+                      session_len=G)
+    trace = workloads.make_traces("stationary", N, 1, T, seed=37)[0]
+    edges = _device_assignment(topo, trace).reshape(K, G)
+    assert all(len(set(chunk.tolist())) == 1 for chunk in edges)
+    st = _assert_routed_stream_matches(topo, trace, G)
+    # each chunk's busy edge steps every position: 3 edges x G at level 0
+    assert st.node_steps >= K * 3 * G
+
+
+def test_stream_chunk_with_an_idle_origin_node():
+    """A chunk whose ids all hash to origin node 0: node 1 gets no request
+    there, and the loop runs to node 0's load alone."""
+    topo = fleet.tree(n_objects=N, widths=(3, 2), kinds=("lru", "plfu"),
+                      capacities=(5, 11), routers=("sticky", "hash"),
+                      session_len=16)
+    origin = router.route_level(np.arange(N), 2, "hash", seed=1)
+    trace = workloads.make_traces("churn", N, 1, T, seed=43)[0]
+    node0_ids = np.flatnonzero(origin == 0)
+    trace[G:2 * G] = node0_ids[trace[G:2 * G] % len(node0_ids)]
+    assert (origin[trace[G:2 * G]] == 0).all()
+    assert (origin[trace] == 1).any()
+    _assert_routed_stream_matches(topo, trace, G)
+
+
+@pytest.mark.parametrize("dense", ["plfua_dyn", "telemetry"])
+def test_stream_node_steps_of_dense_levels(dense):
+    """A level that keeps the dense scan steps every node at every position
+    (plfua_dyn's global-time refresh; every level with telemetry), the
+    compacted levels their busiest node's load."""
+    kinds = ("lru", "plfua_dyn") if dense == "plfua_dyn" else ("lru", "plfu")
+    topo = fleet.tree(n_objects=N, widths=(3, 1), kinds=kinds, capacities=(5, 13),
+                      refresh=(0, 30))
+    tel = TelemetrySpec(window=25) if dense == "telemetry" else None
+    trace = workloads.make_traces("churn", N, 1, T, seed=47)[0]
+    assignment = topo.assignment(trace)
+    fs, _ = _run_stream(StreamConfig(topo=topo, chunk_len=G, telemetry=tel),
+                        trace, assignment)
+    st = fs.stats()
+    ref = simulate_fleet_reference(topo, trace, assignment)
+    want = _recount_node_steps(topo, trace, assignment, ref.level_hit, G,
+                               telemetry=tel is not None)
+    assert st.node_steps == want
+    if tel is not None:
+        assert st.node_steps == st.lanes
+    else:
+        # the compacted edge level runs less than the dense grid
+        assert st.lanes_valid <= st.node_steps < st.lanes
+
+
 def test_stream_edge8_origin4_fleet_matches_bounded():
     """The photo-CDN shape at N = 3,000: 8 sticky PLFUA edges (rate 0.02,
     hot set 2 x C) over 4 hash-partitioned PLFU origin nodes, sessions of 64
@@ -434,3 +516,5 @@ def test_stream_edge8_origin4_fleet_matches_bounded():
     # miss, at one origin node
     assert st.lanes == 6 * 256 * 12
     assert 6 * 256 < st.lanes_valid < 2 * 6 * 256
+    # the compacted loops run each level to its busiest node's load
+    assert st.lanes_valid < st.node_steps < st.lanes // 2
