@@ -608,6 +608,10 @@ def _refresh_cell(spec: PolicySpec, cap, instrument, sizes, cap_bytes, og):
     fire schedule) and :func:`stream_chunked_scan` (unbounded, traced global
     time): a masked per-request ``step`` scan over one refresh chunk, then a
     per-chunk ``refresh_hot`` applied where the chunk's fire flag is set.
+    The refresh sits under a ``lax.cond`` (the flag depends on the stream
+    position alone, so vmapped samples and nodes share it), so a sub-chunk
+    that ends no refresh period skips the estimate-all + top-k: a chunk
+    whose length shares little with the period scans many of them.
     Keeping one cell guarantees the two drivers are the same program on the
     same inputs — the streaming equivalence tests pin exactly that."""
 
@@ -622,16 +626,15 @@ def _refresh_cell(spec: PolicySpec, cap, instrument, sizes, cap_bytes, og):
     def chunk(s, inp):
         xs, acts, fire_c = inp
         s, out = jax.lax.scan(f, s, (xs, acts))
-        refreshed = refresh_hot(spec, s)
+        refreshed = jax.lax.cond(
+            fire_c, functools.partial(refresh_hot, spec), lambda s: s, s
+        )
         if instrument:
-            diff = s["hot"] != refreshed["hot"]
-            churn = jnp.where(fire_c, diff.sum().astype(jnp.int32), 0)
-            chunk_ev = {"fired": fire_c, "churn": churn}
+            diff = s["hot"] != refreshed["hot"]  # all False where none fired
+            chunk_ev = {"fired": fire_c, "churn": diff.sum().astype(jnp.int32)}
             if og is not None:
-                chunk_ev["churn_g"] = jnp.where(
-                    fire_c, diff.astype(jnp.int32) @ og, 0
-                )
-        s = jax.tree_util.tree_map(lambda o, r: jnp.where(fire_c, r, o), s, refreshed)
+                chunk_ev["churn_g"] = diff.astype(jnp.int32) @ og
+        s = refreshed
         if instrument:
             return s, (out, chunk_ev)
         return s, out

@@ -22,9 +22,9 @@ shards the sample axis with on-device trace generation (weak scaling) —
 both honour placement. The legacy two-tier API in :mod:`repro.cdn` is a
 thin wrapper over depth-2 topologies. For unbounded request streams (line
 rate, not one bounded trace per call) see :mod:`repro.fleet.stream`:
-``FleetStream`` / ``stream_fleet`` push fixed-shape chunks through a
-donated carry, bit-identical to ``simulate_fleet`` on the concatenated
-trace.
+``FleetStream`` / ``stream_fleet`` push fixed-shape chunks of the same
+chunk program a bounded ``simulate_fleet`` applies once, through a donated
+carry, bit-identical to ``simulate_fleet`` on the concatenated trace.
 """
 from repro.fleet import placement
 from repro.fleet.topology import (
@@ -37,7 +37,6 @@ from repro.fleet.sim import (
     masked_scan,
     simulate_fleet,
     simulate_fleet_batch,
-    tier_counters,
 )
 from repro.fleet.reference import (
     FleetReferenceResult,
@@ -88,7 +87,6 @@ __all__ = [
     "fleet_report",
     "mgmt_ops",
     "masked_scan",
-    "tier_counters",
     "fleet_mesh",
     "mesh_size",
     "FAST_KINDS",

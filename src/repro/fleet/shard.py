@@ -2,13 +2,16 @@
 
 Two complementary shardings of :func:`repro.fleet.sim.simulate_fleet`:
 
-* **Edge-sharded** (:func:`simulate_fleet_sharded`) — the edge fleet's
-  vmapped scan splits across a 1-axis device mesh; each device runs its
-  local slice of edges over the (replicated) trace, then a single ``psum``
-  collective rebuilds the *global* served mask so the upper tiers — small,
-  replicated on every device — consume exactly the fleet-wide miss stream.
-  Decision-identical to the single-device path (tests run it under forced
-  host devices).
+* **Edge-sharded** (:func:`simulate_fleet_sharded`) — the engine's chunk
+  program (:mod:`repro.fleet.sim`, the one a bounded run applies once and
+  ``FleetStream`` pushes) runs inside a ``shard_map`` over a 1-axis device
+  mesh. Each device holds its contiguous slice of the edge level and a
+  replica of the upper levels; level 0 scans only the local edges over the
+  (replicated) trace, then one ``psum`` rebuilds the *global* served mask —
+  once for the whole chunk in the level-major engine, once a step in the
+  time-major one — so the upper tiers consume exactly the fleet-wide miss
+  stream. Decision-identical to the single-device path (tests run it under
+  forced host devices).
 
 * **Sample-sharded** (:func:`simulate_fleet_device`) — weak scaling: the
   sample axis splits across the mesh and every shard *synthesizes its own
@@ -60,132 +63,40 @@ def mesh_size(mesh: Mesh | None) -> int:
 def _edge_sharded_fn(topo: Topology, mesh: Mesh):
     axis = mesh.axis_names[0]
     D = mesh.shape[axis]
-    specs0 = topo.levels[0]
-    E = len(specs0)
-    if E % D:
-        raise ValueError(
-            f"edge count {E} must divide over the {D}-device mesh"
-        )
-    s0 = specs0[0]
-
-    def edge_shard(states, active, caps, trace):
-        # local slice of the edge fleet: E/D masked scans on this device
-        states, hits = jax.vmap(
-            lambda st, act, cap: sim_mod.masked_scan(s0, st, trace, act, cap)
-        )(states, active, caps)
-        # cross-tier miss aggregation: one collective rebuilds the global
-        # served mask (exactly one edge is active per t, so sum == any)
-        served = jax.lax.psum(hits.any(axis=0).astype(jnp.int32), axis) > 0
-        return states, hits, served
-
-    sharded = jax.shard_map(
-        edge_shard,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P()),
-        out_specs=(P(axis), P(axis), P()),
-    )
-
-    @jax.jit
-    def run(trace, assignment):
-        trace = trace.astype(jnp.int32)
-        assignment = assignment.astype(jnp.int32)
-        assigns = sim_mod.level_assignments(topo, trace, assignment)
-        active0 = assigns[0][None, :] == jnp.arange(E, dtype=jnp.int32)[:, None]
-        states0 = sim_mod.stack_level_state(specs0)
-        caps0 = jnp.array([s.capacity for s in specs0], jnp.int32)
-        edge_states, edge_hits, edge_hit = sharded(states0, active0, caps0, trace)
-        demand = ~edge_hit
-        hits_up, counters_up, states_up, demand = sim_mod.upper_levels(
-            topo, trace, assigns, demand
-        )
-        all_hits = [edge_hits, *hits_up]
-        return {
-            "hit": tuple(h.any(axis=0) for h in all_hits),
-            "node_hit": tuple(all_hits),
-            "tiers": (
-                sim_mod.tier_counters(s0, edge_hits, active0, trace, edge_states),
-                *counters_up,
-            ),
-            "states": (edge_states, *states_up),
-            "origin_miss": demand,
-        }
-
-    return run
-
-
-# ------------------------------------------- edge-sharded, placed topologies
-@functools.lru_cache(maxsize=None)
-def _edge_sharded_placed_fn(topo: Topology, mesh: Mesh):
-    """Edge-sharded execution of a placement-enabled topology.
-
-    Cross-tier placement couples the levels at every trace position, so the
-    whole time-major scan (``sim._placed_run``) moves *inside* the shard_map
-    body: each device carries its contiguous slice of the edge fleet plus a
-    replica of the upper tiers, and one ``psum`` per step rebuilds the
-    global edge-served bit (exactly one device owns the assigned edge).
-    Upper-tier updates are pure functions of replicated inputs, so every
-    device computes them identically — bit-parity with the single-device
-    placed engine is asserted in tests/test_placement.py."""
-    axis = mesh.axis_names[0]
-    D = mesh.shape[axis]
-    specs0 = topo.levels[0]
-    E = len(specs0)
+    E = len(topo.levels[0])
     if E % D:
         raise ValueError(f"edge count {E} must divide over the {D}-device mesh")
-    L = topo.n_levels
-
-    def body(states0, caps0, trace, assigns):
-        states, pstates, fills, admitted, hit_lv = sim_mod._placed_run(
-            topo,
-            trace,
-            list(assigns),
-            level0_states=states0,
-            level0_caps=caps0,
-            edge_axis=axis,
-        )
-        return (
-            tuple(states),
-            pstates,
-            tuple(fills),
-            tuple(admitted),
-            tuple(hit_lv),
-        )
-
-    edge_or_rep = lambda l: P(axis) if l == 0 else P()
-    parsed_admit = [
-        l for l, p in enumerate(topo.placements) if p == "admit"
-    ]
-    out_specs = (
-        tuple(edge_or_rep(l) for l in range(L)),
-        {l: edge_or_rep(l) for l in parsed_admit},
-        tuple(edge_or_rep(l) for l in range(L)),
-        tuple(edge_or_rep(l) for l in range(L)),
-        tuple(P() for _ in range(L)),
-    )
-    sharded = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis), P(), P()),
-        out_specs=out_specs,
-        check_vma=False,  # upper tiers are replicated by construction (the
-        # per-step psum), which the varying-axes check cannot see through
-        # the scan
-    )
+    # level 0's carry and per-node series split over the edge axis,
+    # everything else replicated
+    lvl = tuple(P(axis) if l == 0 else P() for l in range(topo.n_levels))
 
     @jax.jit
     def run(trace, assignment):
         trace = trace.astype(jnp.int32)
         assignment = assignment.astype(jnp.int32)
-        assigns = sim_mod.level_assignments(topo, trace, assignment)
-        states0 = sim_mod.stack_level_state(specs0)
-        caps0 = jnp.array([s.capacity for s in specs0], jnp.int32)
-        states, pstates, fills, admitted, hit_lv = sharded(
-            states0, caps0, trace, tuple(assigns)
+        chunk, carry0 = sim_mod._chunk_program(topo, trace.shape[0], edge_axis=axis)
+        carry_specs = {k: P() for k in carry0}
+        carry_specs["acc"] = lvl
+        if topo.has_placement:
+            pstates = carry0["placed"][1]
+            carry_specs["placed"] = (lvl, {l: lvl[l] for l in pstates}, lvl, lvl)
+        else:
+            carry_specs["states"] = lvl
+        sharded = jax.shard_map(
+            chunk,
+            mesh=mesh,
+            in_specs=(carry_specs, P(), P()),
+            out_specs=(
+                carry_specs,
+                {"hit": (P(),) * len(lvl), "node_hit": lvl, "origin_miss": P()},
+            ),
+            # the upper levels are replicated by construction (the psum of
+            # the edge-served mask), which the varying-axes check cannot see
+            # through the scans; the carry's node_steps is per device and
+            # left out of the result
+            check_vma=False,
         )
-        return sim_mod.assemble_placed(
-            topo, assigns, list(states), pstates, list(fills),
-            list(admitted), list(hit_lv),
-        )
+        return sim_mod._bounded_result(topo, *sharded(carry0, trace, assignment))
 
     return run
 
@@ -197,14 +108,11 @@ def simulate_fleet_sharded(
 
     Falls back to the single-device vmap path when ``mesh`` is absent or has
     one device (the documented single-device fallback). Placement-enabled
-    topologies run the time-major scan inside the mesh (see
-    ``_edge_sharded_placed_fn``); sample-sharded execution
-    (``simulate_fleet_device``) honours placement automatically — every
-    sample replica dispatches through ``sim._simulate_fleet_impl``."""
+    topologies run the time-major chunk program inside the mesh;
+    sample-sharded execution (``simulate_fleet_device``) honours placement
+    too — every sample replica is one bounded run."""
     if mesh_size(mesh) == 1:
         return sim_mod.simulate_fleet(topo, trace, assignment)
-    if topo.has_placement:
-        return _edge_sharded_placed_fn(topo, mesh)(trace, assignment)
     return _edge_sharded_fn(topo, mesh)(trace, assignment)
 
 

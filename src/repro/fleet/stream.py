@@ -1,11 +1,11 @@
 """Streaming fleet engine: an unbounded request stream as fixed-shape chunks.
 
-The bounded engines in :mod:`repro.fleet.sim` take one trace array per call —
-fine for the paper's 500k-request replications, useless for "millions of
-users": production load is a *stream*, and the headline metric (management
-CPU time = energy) only means something at sustained line rate. This module
-runs that stream as a sequence of ``chunk_len``-shaped chunks with three
-invariants:
+A bounded fleet run (:func:`repro.fleet.sim.simulate_fleet`) takes one trace
+array per call — fine for the paper's 500k-request replications, useless
+for "millions of users": production load is a *stream*, and the headline
+metric (management CPU time = energy) only means something at sustained
+line rate. This module runs that stream as a sequence of
+``chunk_len``-shaped chunks with three invariants:
 
 * **Donated carry.** Every push consumes the carry (cache directory, sketch
   rows, ARC lists, placement sketches, counter accumulators) via
@@ -14,16 +14,18 @@ invariants:
   the chunk itself, not the fleet state. The caller-visible contract is that
   :meth:`FleetStream.push` owns the carry — user code never touches it.
 
-* **Bit-identity with the bounded engines.** K pushed chunks reproduce
-  ``simulate_fleet`` (or ``jax_cache.simulate``) on the concatenated trace
-  *exactly* — hit series, final states, tier counters, grouped telemetry
-  series, eviction-pressure channels. plfua_dyn's global-time hot-set
-  refresh is the hard part: the stream scans gcd(refresh, chunk_len)
-  sub-chunks and fires a *traced* boundary test on the global position
-  (``jax_cache.stream_chunked_scan`` / the same ``sim._placed_chunk_fn``
-  cell as the bounded placed engine), reproducing the bounded fire schedule
-  for any chunk length. Telemetry stitches because the window divides the
-  chunk (enforced at config time), so every chunk emits whole windows.
+* **One chunk program.** The level-major and placed engines *are* chunk
+  programs (:mod:`repro.fleet.sim`); a bounded run applies the same
+  function once to a chunk as long as its trace. So K pushed chunks equal
+  one chunk of their concatenation — hit series, final states, tier
+  counters, grouped telemetry series, eviction-pressure channels — when
+  the carry, its stream position ``t0`` and the series stitch: plfua_dyn's
+  global-time hot-set refresh fires on a *traced* boundary test against
+  ``t0`` in gcd(refresh, chunk_len) sub-chunks, so any chunk length runs
+  the same refresh schedule; telemetry stitches because the window divides
+  the chunk (enforced at config time), so every chunk emits whole windows.
+  The flat fast path below is its own program, pinned against
+  ``jax_cache.simulate``.
 
 * **Double-buffered on-device synthesis.** :func:`stream_fleet` dispatches
   the jitted generator for chunk ``t+1`` (``workloads.device
@@ -50,7 +52,6 @@ tests/test_stream.py.
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 
 import jax
@@ -59,10 +60,8 @@ import numpy as np
 
 from repro.cdn import router as router_mod
 from repro.core import energy, jax_cache
-from repro.core.jax_cache import PolicySpec
 from repro.fleet import sim as sim_mod
 from repro.fleet.topology import Topology
-from repro.telemetry import spec as telemetry_spec
 from repro.telemetry.spec import TelemetrySpec
 from repro.workloads import device as device_mod
 
@@ -135,8 +134,8 @@ class StreamConfig:
 class StreamStats:
     """Rollup of one streaming run.
 
-    ``tiers`` follows the bounded engines' per-level counter-dict layout
-    (``sim.tier_counters`` / ``assemble_placed``); the fast path reports the
+    ``tiers`` follows ``simulate_fleet``'s per-level counter-dict layout
+    (the engines' shared ``sim._tier_from_acc``); the fast path reports the
     reduced dict its carry can derive (requests/hits/count[, inserts]).
     ``telemetry``/``telemetry_pressure`` are the stitched per-level series,
     shaped exactly like ``simulate_fleet``'s on the concatenated trace.
@@ -188,392 +187,6 @@ class StreamStats:
         if not self.elapsed_s:
             return None
         return energy.mgmt_energy_j(self.elapsed_s) / max(1, self.requests)
-
-
-def _sub_len(spec: PolicySpec, chunk_len: int) -> int | None:
-    """Telemetry chunk length of a level inside one stream chunk (the gcd
-    sub-chunk its fired/churn events are emitted over), or None for kinds
-    without chunk-shaped events."""
-    if spec.kind != "plfua_dyn":
-        return None
-    return jax_cache.stream_sub_len(spec, chunk_len)
-
-
-def _stream_masked_scan(
-    spec, state, trace, active, cap, *, t0, instrument=False, sizes=None,
-    cap_bytes=None, og=None,
-):
-    """The streaming twin of ``sim.masked_scan``: identical for every kind
-    except plfua_dyn, which routes through ``stream_chunked_scan`` so its
-    global-time refresh consults the traced stream position ``t0``."""
-    with jax.named_scope("repro.step"):
-        if spec.kind == "plfua_dyn":
-            return jax_cache.stream_chunked_scan(
-                spec, state, trace, active, cap, t0=t0, instrument=instrument,
-                sizes=sizes, cap_bytes=cap_bytes, og=og,
-            )
-        return sim_mod.masked_scan(
-            spec, state, trace, active, cap, instrument=instrument, sizes=sizes,
-            cap_bytes=cap_bytes, og=og,
-        )
-
-
-def _acc_keys(spec: PolicySpec, sized: bool) -> tuple[str, ...]:
-    """Counter accumulators a level needs beyond requests/hits, mirroring
-    ``sim.tier_counters``: kinds whose insert count is not carried in state
-    accumulate it from per-chunk miss sums; plfua also accumulates its
-    hot-gated request count; sized runs accumulate byte traffic."""
-    keys = ["requests", "hits"]
-    if spec.kind == "plfua":
-        keys.append("admitted")
-        if not spec.capacity_bytes:
-            keys.append("inserts")
-    elif spec.kind not in jax_cache.SKETCH_POLICY_KINDS:
-        if not spec.capacity_bytes:
-            keys.append("inserts")
-    if sized:
-        keys += ["req_bytes", "hit_bytes"]
-    return tuple(keys)
-
-
-def _zero_acc(topo: Topology, sized: bool):
-    return tuple(
-        {
-            k: jnp.zeros((len(lvl),), jnp.int32)
-            for k in _acc_keys(lvl[0], sized)
-        }
-        for lvl in topo.levels
-    )
-
-
-def _accumulate_level(spec, acc_l, active, hits, trace, states_l, sz_t):
-    """One chunk's contribution to a level's counter accumulators."""
-    out = dict(acc_l)
-    out["requests"] = acc_l["requests"] + active.sum(-1).astype(jnp.int32)
-    out["hits"] = acc_l["hits"] + hits.sum(-1).astype(jnp.int32)
-    miss = active & ~hits
-    if spec.kind == "plfua":
-        admitted = jnp.take(states_l["hot"], trace, axis=-1)
-        if "inserts" in acc_l:
-            out["inserts"] = acc_l["inserts"] + (miss & admitted).sum(-1).astype(
-                jnp.int32
-            )
-        out["admitted"] = acc_l["admitted"] + (active & admitted).sum(-1).astype(
-            jnp.int32
-        )
-    elif "inserts" in acc_l:
-        out["inserts"] = acc_l["inserts"] + miss.sum(-1).astype(jnp.int32)
-    if sz_t is not None:
-        out["req_bytes"] = acc_l["req_bytes"] + (active * sz_t).sum(-1)
-        out["hit_bytes"] = acc_l["hit_bytes"] + (hits * sz_t).sum(-1)
-    return out
-
-
-def _tier_from_acc(spec: PolicySpec, acc_l, state_l, *, inserts=None, admitted=None):
-    """Assemble one level's final counter dict from its accumulators and
-    final state — the streaming closure of ``sim.tier_counters`` (placed
-    runs pass their carried ``fills``/``admitted`` instead)."""
-    if inserts is None:
-        if spec.capacity_bytes or spec.kind in jax_cache.SKETCH_POLICY_KINDS:
-            inserts = state_l["inserts"]
-        else:
-            inserts = acc_l["inserts"]
-    if admitted is None:
-        if spec.kind == "plfua":
-            admitted = acc_l["admitted"]
-        elif spec.kind in jax_cache.SKETCH_POLICY_KINDS:
-            admitted = acc_l["hits"] + inserts
-        else:
-            admitted = acc_l["requests"]
-    count = state_l["count"]
-    tier = {
-        "requests": acc_l["requests"],
-        "hits": acc_l["hits"],
-        "admitted_requests": admitted,
-        "inserts": inserts,
-        "evictions": inserts - count,
-        "count": count,
-    }
-    if "req_bytes" in acc_l:
-        tier["req_bytes"] = acc_l["req_bytes"]
-        tier["hit_bytes"] = acc_l["hit_bytes"]
-    if spec.capacity_bytes:
-        tier["bytes"] = state_l["bytes"]
-    return tier
-
-
-# ------------------------------------------------------- level-major chunks
-def _build_level_major(cfg: StreamConfig, sizes, og, groups):
-    topo, telemetry, G = cfg.topo, cfg.telemetry, cfg.chunk_len
-    instrument = telemetry is not None
-    grouped = og is not None
-
-    def level_major_chunk(carry, trace, assignment):
-        t0 = carry["t0"]
-        trace = trace.astype(jnp.int32)
-        with jax.named_scope("repro.route"):
-            assigns = sim_mod.level_assignments(topo, trace, assignment, t0=t0)
-        groups_t = None if groups is None else groups[trace]
-        sz_t = None if sizes is None else jnp.take(sizes, trace, axis=-1)
-        demand = jnp.ones((G,), jnp.bool_)
-        node_steps = carry["node_steps"]
-        new_states, new_acc = [], []
-        hit_lv, node_hit, series, pressure = [], [], [], []
-        for l, specs in enumerate(topo.levels):
-            with jax.named_scope(f"repro.level{l}"):
-                s0 = specs[0]
-                K = len(specs)
-                active = (
-                    assigns[l][None, :] == jnp.arange(K, dtype=jnp.int32)[:, None]
-                ) & demand[None, :]
-                caps = jnp.array([s.capacity for s in specs], jnp.int32)
-                if s0.capacity_bytes:
-                    caps_b = jnp.array([s.capacity_bytes for s in specs], jnp.int32)
-                    out = jax.vmap(
-                        lambda st, act, cap, capb: _stream_masked_scan(
-                            s0, st, trace, act, cap, t0=t0, instrument=instrument,
-                            sizes=sizes, cap_bytes=capb, og=og,
-                        )
-                    )(carry["states"][l], active, caps, caps_b)
-                else:
-                    out = jax.vmap(
-                        lambda st, act, cap: _stream_masked_scan(
-                            s0, st, trace, act, cap, t0=t0, instrument=instrument,
-                            sizes=sizes, og=og,
-                        )
-                    )(carry["states"][l], active, caps)
-                if instrument:
-                    states_l, hits, events = out
-                    with jax.named_scope("repro.telemetry"):
-                        series.append(
-                            jax_cache.telemetry_series(
-                                s0, telemetry, G, hits, events, active=active,
-                                groups_t=groups_t, chunk_len=_sub_len(s0, G),
-                            )
-                        )
-                        if grouped:
-                            pressure.append(
-                                telemetry_spec.windowed_pressure(
-                                    telemetry.window, groups_t, events["evict_g"],
-                                    xp=jnp,
-                                )
-                            )
-                else:
-                    states_l, hits = out
-                if sim_mod.compacts(s0, instrument):
-                    # the level's loop runs to its busiest node's load
-                    loads = active.sum(-1, dtype=jnp.int32)
-                    node_steps = node_steps + jnp.int32(K) * loads.max()
-                else:
-                    node_steps = node_steps + jnp.int32(K * G)
-                new_states.append(states_l)
-                new_acc.append(
-                    _accumulate_level(
-                        s0, carry["acc"][l], active, hits, trace, states_l, sz_t
-                    )
-                )
-                node_hit.append(hits)
-                hit_l = hits.any(axis=0)
-                hit_lv.append(hit_l)
-                demand = demand & ~hit_l
-        new_carry = {
-            "states": tuple(new_states),
-            "acc": tuple(new_acc),
-            "origin": carry["origin"] + demand.sum(dtype=jnp.int32),
-            "node_steps": node_steps,
-            "t0": t0 + jnp.int32(G),
-        }
-        out = {
-            "hit": tuple(hit_lv),
-            "node_hit": tuple(node_hit),
-            "origin_miss": demand,
-        }
-        if instrument:
-            out["telemetry"] = tuple(series)
-            if grouped:
-                out["telemetry_pressure"] = tuple(pressure)
-        return new_carry, out
-
-    carry0 = {
-        "states": tuple(sim_mod.stack_level_state(lvl) for lvl in topo.levels),
-        "acc": _zero_acc(topo, sizes is not None),
-        "origin": jnp.zeros((), jnp.int32),
-        "node_steps": jnp.zeros((), jnp.int32),
-        "t0": jnp.zeros((), jnp.int32),
-    }
-    # the dense grid: every node at every position of the chunk
-    lanes = G * topo.n_nodes
-    return jax.jit(level_major_chunk, donate_argnums=0), carry0, lanes
-
-
-# ------------------------------------------------------------ placed chunks
-def _build_placed(cfg: StreamConfig, sizes, og, groups):
-    topo, telemetry, G = cfg.topo, cfg.telemetry, cfg.chunk_len
-    instrument = telemetry is not None
-    grouped = og is not None
-    specs, dyn_levels, placed0, step_t = sim_mod._placed_prelude(
-        topo, instrument=instrument, sizes=sizes, og=og
-    )
-    # sub-chunks tile the chunk so every whole multiple of every dyn level's
-    # refresh period is a sub-chunk boundary (sub | gcd(periods) | period);
-    # the traced fire test then reproduces the bounded schedule exactly
-    gdyn = sim_mod._dyn_chunk(topo)
-    sub = math.gcd(gdyn, G) if gdyn else G
-    n_sub = G // sub
-    chunk_body = sim_mod._placed_chunk_fn(
-        specs, dyn_levels, step_t, instrument=instrument, og=og
-    )
-
-    def placed_chunk(carry, trace, assignment):
-        t0 = carry["t0"]
-        trace = trace.astype(jnp.int32)
-        with jax.named_scope("repro.route"):
-            assigns = sim_mod.level_assignments(topo, trace, assignment, t0=t0)
-        groups_t = None if groups is None else groups[trace]
-        sz_t = None if sizes is None else jnp.take(sizes, trace, axis=-1)
-        t_arr = t0 + jnp.arange(G, dtype=jnp.int32)
-        valid = jnp.ones((G,), jnp.bool_)
-        ends = t0 + (jnp.arange(n_sub, dtype=jnp.int32) + 1) * jnp.int32(sub)
-        if dyn_levels:
-            fire = jnp.stack(
-                [
-                    ends % jnp.int32(specs[l].effective_refresh) == 0
-                    for l in dyn_levels
-                ],
-                axis=1,
-            )
-        else:
-            fire = jnp.zeros((n_sub, 0), jnp.bool_)
-        tile = lambda a: a.reshape(n_sub, sub, *a.shape[1:])
-        with jax.named_scope("repro.step"):
-            placed, out = jax.lax.scan(
-                chunk_body,
-                carry["placed"],
-                (
-                    (
-                        tile(t_arr),
-                        tile(trace),
-                        tile(valid),
-                        tuple(tile(a) for a in assigns),
-                    ),
-                    fire,
-                ),
-            )
-        untiled = sim_mod._placed_untile(
-            out, G, topo.n_levels, dyn_levels, fire, instrument=instrument, og=og
-        )
-        if instrument:
-            hit_lv, tel_lv = untiled
-        else:
-            hit_lv = untiled
-        # mirror assemble_placed per chunk: per-node activity from the hit
-        # series + demand chain, counters accumulated, telemetry bucketed
-        demand = jnp.ones((G,), jnp.bool_)
-        new_acc, node_hit, series, pressure = [], [], [], []
-        for l in range(topo.n_levels):
-            K = len(topo.levels[l])
-            active = (
-                assigns[l][None, :] == jnp.arange(K, dtype=jnp.int32)[:, None]
-            ) & demand[None, :]
-            nh = active & hit_lv[l][None, :]
-            acc_l = dict(carry["acc"][l])
-            acc_l["requests"] = acc_l["requests"] + active.sum(-1).astype(jnp.int32)
-            acc_l["hits"] = acc_l["hits"] + nh.sum(-1).astype(jnp.int32)
-            if sz_t is not None:
-                acc_l["req_bytes"] = acc_l["req_bytes"] + (
-                    active * sz_t[None, :]
-                ).sum(-1)
-                acc_l["hit_bytes"] = acc_l["hit_bytes"] + (nh * sz_t[None, :]).sum(-1)
-            new_acc.append(acc_l)
-            node_hit.append(nh)
-            if instrument:
-                with jax.named_scope("repro.telemetry"):
-                    ev = tel_lv[l]
-                    per_node = lambda s: active & s[None, :]
-                    aging = ev.get("aging")
-                    if grouped:
-                        evict_g = active[:, :, None] * ev["evict_g"][None, :, :]
-                        series.append(
-                            telemetry_spec.grouped_series_from_run(
-                                telemetry.window,
-                                G,
-                                telemetry.n_groups,
-                                groups_t,
-                                hits=nh,
-                                active=active,
-                                fills=per_node(ev["fill"]),
-                                evictions_g=evict_g,
-                                occupancy_g=ev["count_g"],
-                                offers=per_node(ev["offer"]),
-                                aging=None if aging is None else per_node(aging),
-                                fired=ev.get("fired"),
-                                churn_g=ev.get("churn_g"),
-                                hit_bytes=None if sz_t is None else nh * sz_t[None, :],
-                                miss_bytes=(
-                                    None
-                                    if sz_t is None
-                                    else (active & ~nh) * sz_t[None, :]
-                                ),
-                                chunk_len=sub,
-                                xp=jnp,
-                            )
-                        )
-                        pressure.append(
-                            telemetry_spec.windowed_pressure(
-                                telemetry.window, groups_t, evict_g, xp=jnp
-                            )
-                        )
-                    else:
-                        series.append(
-                            telemetry_spec.series_from_run(
-                                telemetry.window,
-                                G,
-                                hits=nh,
-                                active=active,
-                                fills=per_node(ev["fill"]),
-                                evictions=active * ev["evict"][None, :],
-                                occupancy=ev["count"],
-                                offers=per_node(ev["offer"]),
-                                aging=None if aging is None else per_node(aging),
-                                fired=ev.get("fired"),
-                                churn=ev.get("churn"),
-                                hit_bytes=None if sz_t is None else nh * sz_t[None, :],
-                                miss_bytes=(
-                                    None
-                                    if sz_t is None
-                                    else (active & ~nh) * sz_t[None, :]
-                                ),
-                                chunk_len=sub,
-                                xp=jnp,
-                            )
-                        )
-            demand = demand & ~hit_lv[l]
-        new_carry = {
-            "placed": placed,
-            "acc": tuple(new_acc),
-            "origin": carry["origin"] + demand.sum(dtype=jnp.int32),
-            "t0": t0 + jnp.int32(G),
-        }
-        out = {
-            "hit": tuple(hit_lv),
-            "node_hit": tuple(node_hit),
-            "origin_miss": demand,
-        }
-        if instrument:
-            out["telemetry"] = tuple(series)
-            if grouped:
-                out["telemetry_pressure"] = tuple(pressure)
-        return new_carry, out
-
-    carry0 = {
-        "placed": placed0,
-        "acc": _zero_acc(topo, sizes is not None),
-        "origin": jnp.zeros((), jnp.int32),
-        "t0": jnp.zeros((), jnp.int32),
-    }
-    # time-major: one gathered node-step per level and position
-    lanes = G * topo.n_levels
-    return jax.jit(placed_chunk, donate_argnums=0), carry0, lanes
 
 
 # --------------------------------------------------- fast compact-lane path
@@ -692,26 +305,18 @@ class FleetStream:
     def __init__(self, cfg: StreamConfig, *, sizes=None, groups=None):
         self.cfg = cfg
         self._sizes = None if sizes is None else jnp.asarray(sizes, jnp.int32)
-        telemetry = cfg.telemetry
-        if telemetry is not None and telemetry.n_groups:
-            if groups is None:
-                raise ValueError("telemetry.n_groups > 0 requires a groups catalogue")
-            self._groups = jnp.asarray(groups, jnp.int32)
-            og = telemetry_spec.group_onehot(
-                self._groups, telemetry.n_groups, jnp
-            )
-        else:
-            self._groups, og = None, None
+        telemetry, topo, G = cfg.telemetry, cfg.topo, cfg.chunk_len
+        self._groups, og = sim_mod._group_tables(telemetry, groups)
         if cfg.fast:
             self._push_fn, self._carry, self._lanes = _build_fast(cfg, self._sizes)
-        elif cfg.topo.has_placement:
-            self._push_fn, self._carry, self._lanes = _build_placed(
-                cfg, self._sizes, og, self._groups
-            )
         else:
-            self._push_fn, self._carry, self._lanes = _build_level_major(
-                cfg, self._sizes, og, self._groups
+            chunk, self._carry = sim_mod._chunk_program(
+                topo, G, telemetry, sizes=self._sizes, og=og, groups=self._groups
             )
+            self._push_fn = jax.jit(chunk, donate_argnums=0)
+            # the dense node x position grid (level-major), or one gathered
+            # node-step per level and position (time-major)
+            self._lanes = G * (topo.n_levels if topo.has_placement else topo.n_nodes)
         self.chunks = 0
         self._series = (
             [[] for _ in cfg.topo.levels] if telemetry is not None else None
@@ -782,15 +387,13 @@ class FleetStream:
         state), laid out exactly like ``simulate_fleet``'s ``states``."""
         if self.cfg.fast:
             return (self._carry["state"],)
-        if self.cfg.topo.has_placement:
-            return tuple(self._carry["placed"][0])
-        return self._carry["states"]
+        return sim_mod._final_states(self.cfg.topo, self._carry)
 
     def stats(self, elapsed_s: float | None = None) -> StreamStats:
-        """Roll the stream up. Counter semantics match the bounded engines
-        exactly (``tier_counters`` / ``assemble_placed``); telemetry series
-        are the per-chunk window series concatenated (bit-identical to the
-        bounded series over the concatenated trace). The host waits for the
+        """Roll the stream up. Counters are folded from the carry as a
+        bounded run folds them; telemetry series are the per-chunk window
+        series concatenated (bit-identical to one chunk of the concatenated
+        trace). The host waits for the
         device's counters inside the span ``repro:sync``."""
         with jax.profiler.TraceAnnotation("repro:sync"):
             return self._stats(elapsed_s)
@@ -801,7 +404,6 @@ class FleetStream:
         if cfg.fast:
             carry = self._carry
             hits = int(carry["hits"])
-            spec = cfg.topo.levels[0][0]
             tier = {
                 "requests": jnp.asarray([requests], jnp.int32),
                 "hits": jnp.asarray([hits], jnp.int32),
@@ -824,20 +426,7 @@ class FleetStream:
             )
         carry = self._carry
         origin = int(carry["origin"])
-        states = self.states()
-        tiers = []
-        if cfg.topo.has_placement:
-            _, _, fills, admitted = carry["placed"]
-            for l, lvl in enumerate(cfg.topo.levels):
-                tiers.append(
-                    _tier_from_acc(
-                        lvl[0], carry["acc"][l], states[l],
-                        inserts=fills[l], admitted=admitted[l],
-                    )
-                )
-        else:
-            for l, lvl in enumerate(cfg.topo.levels):
-                tiers.append(_tier_from_acc(lvl[0], carry["acc"][l], states[l]))
+        tiers = sim_mod._final_tiers(cfg.topo, carry)
         telemetry = pressure = None
         if self._series is not None:
             telemetry = tuple(

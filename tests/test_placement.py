@@ -542,13 +542,14 @@ def test_two_tier_serving_constructor_accepts_placement():
 # ----------------------------------------------------------- padded tail pin
 @pytest.mark.parametrize("r", [7, 29])
 def test_placed_partial_tail_no_leakage(r):
-    """The placed engine pads its time scan to a multiple of the gcd refresh
-    chunk (sim._placed_run). The padded tail must be invisible: with
-    ``T = G*k + r`` the telemetry window series, occupancy snapshots, final
-    states and counters of a prob(1.0) tree (placed engine) must equal the
-    lce tree (level-major engine) bit for bit — padding leakage on either
-    side (phantom occupancy samples, a tail refresh fire, window spill)
-    breaks the identity. The window is chosen to not divide T either."""
+    """A bounded run is one chunk of ``T = G*k + r`` requests, scanned in
+    sub-chunks of gcd(refresh, T), with a partial refresh period at its
+    tail. That tail must be invisible: the telemetry window series,
+    occupancy snapshots, final states and counters of a prob(1.0) tree
+    (placed engine) must equal the lce tree (level-major engine) bit for
+    bit — a tail refresh fire, phantom occupancy samples or window spill on
+    either side breaks the identity. The window is chosen to not divide T
+    either."""
     import jax.numpy as jnp
 
     from repro.telemetry import TelemetrySpec

@@ -1,19 +1,22 @@
-"""Stream ↔ bounded differential suite (PR 10).
+"""Stream ↔ bounded differential suite.
 
-The streaming fleet engine (:mod:`repro.fleet.stream`) promises *bit
-identity*: K pushed chunks of length G reproduce ``simulate_fleet`` (or the
-flat ``jax_cache.simulate``) on the concatenated trace exactly — hit series,
-final states, tier counters, grouped telemetry series stitched across chunk
-boundaries, eviction pressure. That promise is what makes the line-rate
-bench numbers (BENCH_PR10 ``fleet_stream`` group) legitimate measurements of
-*the same algorithms* the paper tables score, so this suite pins it over:
+A bounded fleet run (``simulate_fleet``) is one chunk of the same chunk
+program ``FleetStream`` pushes, as long as the trace. So K pushed chunks of
+length G must reproduce that one chunk (or the flat ``jax_cache.simulate``)
+on the concatenated trace exactly — hit series, final states, tier
+counters, grouped telemetry series stitched across chunk boundaries,
+eviction pressure. What the comparison pins is what a chunk boundary
+touches: the donated carry, the stream position ``t0`` and the stitching
+of telemetry. That promise is what makes the line-rate bench numbers
+(the ``fleet_stream`` benchmark group) legitimate measurements of *the
+same algorithms* the paper tables score, so this suite pins it over:
 
 * all 9 policy kinds × stationary/churn on a depth-2 tree with grouped
   telemetry (level-major engine underneath);
 * the placed engine (lcd / prob / admit) on a plfua_dyn tree, where the
-  stream's traced global-time fire schedule must reproduce the bounded
-  host-side one — including a chunk length that does *not* divide the
-  refresh period (gcd sub-chunking);
+  traced global-time fire schedule of K chunks must reproduce that of one
+  chunk — including a chunk length that does *not* divide the refresh
+  period (gcd sub-chunking);
 * the fast compact-lane path against the dense flat simulator for every
   FAST_KIND (the candidate-prefix bound, tie-breaks included);
 * the double-buffered ``stream_fleet`` driver against a bounded run over
