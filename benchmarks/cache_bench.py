@@ -143,9 +143,61 @@ def kernel_vs_jax(full: bool = False):
     return rows
 
 
+def _scan_of(spec: jax_cache.PolicySpec, batch: int):
+    """A scan of ``jax_cache.step`` from the empty cache over a ``(steps,)``
+    trace, vmapped over ``(batch, steps)`` traces where ``batch > 1``."""
+    import jax
+
+    def scan(tr):
+        return jax.lax.scan(
+            lambda s, x: jax_cache.step(spec, s, x), jax_cache.init_state(spec), tr
+        )[1]
+
+    return scan if batch == 1 else jax.vmap(scan)
+
+
+def step_lanes(full: bool = False):
+    """The crossover ``jax_cache._ONE_HOT_LANES`` is set from: us a serial
+    step of a plfua scan (the paper's C 2,000 and hot set 4,000, or 2% and
+    4% of the row reduced) with one-hot and with index lane access, by row
+    length, unbatched and vmapped. Both forms must give the same hits."""
+    import jax
+
+    lanes = (4_096, 8_192, 16_384, 32_768, 65_536, 100_000) if full else (256, 1_024)
+    batches = (1, 8, 12) if full else (1, 4)
+    steps = 2_048 if full else 128
+    rows = []
+    for n in lanes:
+        cap = 2_000 if full else n // 50
+        spec = jax_cache.PolicySpec("plfua", n_objects=n, capacity=cap, hot_size=2 * cap)
+        for batch in batches:
+            traces = zipf.sample_traces(n, n_samples=batch, trace_len=steps, seed=n + batch)
+            traces = traces[0] if batch == 1 else traces
+            us, hits = {}, {}
+            for form, limit in (("onehot", n), ("index", n - 1)):
+                saved, jax_cache._ONE_HOT_LANES = jax_cache._ONE_HOT_LANES, limit
+                try:
+                    fn = jax.jit(_scan_of(spec, batch))
+                    tr = telemetry.measure(fn, traces, steps=steps, repeats=5)
+                    hits[form] = np.asarray(fn(traces))
+                finally:
+                    jax_cache._ONE_HOT_LANES = saved
+                us[form] = tr.us_per_step
+            same = bool((hits["onehot"] == hits["index"]).all())
+            assert same, f"one-hot and index forms differ at {n} lanes, batch {batch}"
+            for form in us:
+                rows.append((
+                    f"step_lanes/{form}/n{n}/b{batch}", us[form],
+                    f"index_over_onehot={us['index'] / us['onehot']:.3f} "
+                    f"chr={hits[form].mean():.4f} backend={jax.default_backend()}",
+                ))
+    return rows
+
+
 ALL = {
     "cache_py": python_reference,
     "cache_jax": jax_batched,
     "cache_pallas": pallas_kernel,
     "kernel_vs_jax": kernel_vs_jax,
+    "step_lanes": step_lanes,
 }

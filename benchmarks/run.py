@@ -29,8 +29,8 @@ def main() -> None:
         "--only",
         default=None,
         help="comma-separated group list (fig2..fig11, metadata, cache_py, "
-        "cache_jax, cache_pallas, kernel_vs_jax, cdn, cdn_router, cdn_topo, "
-        "fleet_policies, fleet_depth, fleet_placement, fleet_scale, "
+        "cache_jax, cache_pallas, kernel_vs_jax, step_lanes, cdn, cdn_router, "
+        "cdn_topo, fleet_policies, fleet_depth, fleet_placement, fleet_scale, "
         "cache_sizes, fleet_bytes, cache_scan, fleet_scan, fleet_stream, "
         "serving_energy, roofline, cache_roofline, telemetry_timing, "
         "telemetry_overhead, telemetry_tenants) — see docs/benchmarks.md",

@@ -36,6 +36,11 @@ padded back to ``n_objects``, so hits, state, :func:`eviction_count` and
 still: the telemetry path (:func:`instrumented_scan`), ``plfua_dyn``
 (its hot set moves), and every direct caller of :func:`step` (the streaming
 fast path, the fleet engines, :func:`run_chunk`).
+
+:func:`step` reads and writes one lane of a per-object row through
+:func:`_lane` and :func:`_set_lane`: by a one-hot mask on a row of at most
+``_ONE_HOT_LANES`` lanes (profile scope ``repro.onehot``), by index on a
+longer one. Both forms give the same bits; the shape picks the faster.
 """
 from __future__ import annotations
 
@@ -193,9 +198,54 @@ def _masked_argmin(values: jax.Array, mask: jax.Array) -> jax.Array:
         return jnp.argmin(jnp.where(mask, values, _I32_MAX)).astype(jnp.int32)
 
 
+#: rows of at most this many lanes are read and written by a one-hot mask
+#: (:func:`_lane`, :func:`_set_lane`), longer ones by index. A masked pass
+#: over a short row fuses with its neighbours, while an index computed by
+#: an earlier vector op (the request, the argmin's victim) must first reach
+#: the scalar unit, and a vmapped one becomes a gather or scatter; on a long
+#: row the pass itself costs more. On a TPU v5e (benchmark group
+#: ``step_lanes``, PERF.md section 5) the one-hot form wins at 32,768 lanes
+#: unbatched and vmapped over 8 and 12, and loses vmapped at 65,536.
+_ONE_HOT_LANES = 32768
+
+
+def _lane(rows, i: jax.Array):
+    """``row[i]`` of a 1-D row, or the tuple of them for a tuple of rows of
+    one length. On short rows one reduction over the one-hot lane mask,
+    however many rows: each row's lanes are summed as int32 outside the
+    mask, exact since one lane is selected. On long rows the index."""
+    one = not isinstance(rows, tuple)
+    rows = (rows,) if one else rows
+    if rows[0].shape[0] > _ONE_HOT_LANES:
+        out = tuple(row[i] for row in rows)
+    else:
+        with jax.named_scope("repro.onehot"):
+            e = jnp.arange(rows[0].shape[0], dtype=jnp.int32) == i
+            sums = jax.lax.reduce(
+                tuple(jnp.where(e, row, 0).astype(jnp.int32) for row in rows),
+                (jnp.int32(0),) * len(rows),
+                lambda a, b: tuple(p + q for p, q in zip(a, b)),
+                (0,),
+            )
+            out = tuple(v.astype(row.dtype) for v, row in zip(sums, rows))
+    return out[0] if one else out
+
+
+def _set_lane(row: jax.Array, i: jax.Array, new) -> jax.Array:
+    """``row.at[i].set(new(old))``, where ``old()`` reads the lane's old
+    value: ``row[i]`` on a long row. On a short row ``old()`` is the whole
+    row, ``new`` (elementwise in it, scalars besides) runs on every lane and
+    the one-hot lane takes its result, so the write reads nothing back."""
+    if row.shape[0] > _ONE_HOT_LANES:
+        return row.at[i].set(new(lambda: row[i]))
+    with jax.named_scope("repro.onehot"):
+        e = jnp.arange(row.shape[0], dtype=jnp.int32) == i
+        return jnp.where(e, new(lambda: row), row)
+
+
 def _sz(sizes: jax.Array | None, i: jax.Array) -> jax.Array:
     """Per-object size lookup; ``sizes=None`` is the unit-size convention."""
-    return jnp.int32(1) if sizes is None else sizes[i]
+    return jnp.int32(1) if sizes is None else _lane(sizes, i)
 
 
 def _evict_bytes_loop(spec, key, in_cache, count, nbytes, size_x, want, cap_b, sizes, L=None):
@@ -215,12 +265,12 @@ def _evict_bytes_loop(spec, key, in_cache, count, nbytes, size_x, want, cap_b, s
         need = want & fits_ever & (nb + size_x > cap_b) & (cnt > 0)
         v = _masked_argmin(keyarr, ic)
         if spec.kind == "gdsf":
-            credit = jnp.where(need, keyarr[v], credit)
-        ic = ic.at[v].set(ic[v] & ~need)
+            credit = jnp.where(need, _lane(keyarr, v), credit)
+        ic = _set_lane(ic, v, lambda old: old() & ~need)
         cnt = cnt - need.astype(jnp.int32)
         nb = nb - jnp.where(need, _sz(sizes, v), 0)
         if destroy:
-            keyarr = keyarr.at[v].set(jnp.where(need, 0, keyarr[v]))
+            keyarr = _set_lane(keyarr, v, lambda old: jnp.where(need, 0, old()))
         return ic, cnt, nb, keyarr, credit
 
     return jax.lax.fori_loop(
@@ -283,12 +333,14 @@ def step(
     if spec.kind == "wlfu":
         # Slide the window *before* the hit test, as the reference does.
         freq, ring, ptr = state["freq"], state["ring"], state["ptr"]
-        old = ring[ptr]
-        freq = freq.at[jnp.maximum(old, 0)].add(jnp.where(old >= 0, -1, 0))
-        ring = ring.at[ptr].set(x)
+        gone = _lane(ring, ptr)  # the id leaving the window (-1 while it fills)
+        freq = _set_lane(
+            freq, jnp.maximum(gone, 0), lambda old: old() + jnp.where(gone >= 0, -1, 0)
+        )
+        ring = _set_lane(ring, ptr, lambda _: x)
         ptr = (ptr + 1) % spec.window
-        freq = freq.at[x].add(1)
-        hit = in_cache[x]
+        freq = _set_lane(freq, x, lambda old: old() + 1)
+        hit = _lane(in_cache, x)
         insert = (~hit) & fill
         if spec.capacity_bytes:
             size_x = _sz(sizes, x)
@@ -296,7 +348,7 @@ def step(
                 spec, freq, in_cache, count, state["bytes"], size_x, insert, cap_b, sizes
             )
             insert = insert & (nbytes + size_x <= cap_b)
-            in_cache = in_cache.at[x].set(in_cache[x] | insert)
+            in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
             count = count + insert.astype(jnp.int32)
             nbytes = nbytes + jnp.where(insert, size_x, 0)
             return dict(
@@ -305,14 +357,14 @@ def step(
             ), hit
         need_evict = insert & (count >= cap)
         victim = _masked_argmin(freq, in_cache)
-        in_cache = in_cache.at[victim].set(in_cache[victim] & ~need_evict)
-        in_cache = in_cache.at[x].set(in_cache[x] | insert)
+        in_cache = _set_lane(in_cache, victim, lambda old: old() & ~need_evict)
+        in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
         count = count + insert.astype(jnp.int32) - need_evict.astype(jnp.int32)
         return dict(in_cache=in_cache, count=count, freq=freq, ring=ring, ptr=ptr), hit
 
     if spec.kind == "lru":
         last, t = state["last"], state["t"]
-        hit = in_cache[x]
+        hit = _lane(in_cache, x)
         insert = (~hit) & fill
         if spec.capacity_bytes:
             size_x = _sz(sizes, x)
@@ -320,8 +372,8 @@ def step(
                 spec, last, in_cache, count, state["bytes"], size_x, insert, cap_b, sizes
             )
             insert = insert & (nbytes + size_x <= cap_b)
-            in_cache = in_cache.at[x].set(in_cache[x] | insert)
-            last = last.at[x].set(t)
+            in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
+            last = _set_lane(last, x, lambda _: t)
             count = count + insert.astype(jnp.int32)
             nbytes = nbytes + jnp.where(insert, size_x, 0)
             return dict(
@@ -330,9 +382,9 @@ def step(
             ), hit
         need_evict = insert & (count >= cap)
         victim = _masked_argmin(last, in_cache)
-        in_cache = in_cache.at[victim].set(in_cache[victim] & ~need_evict)
-        in_cache = in_cache.at[x].set(in_cache[x] | insert)
-        last = last.at[x].set(t)
+        in_cache = _set_lane(in_cache, victim, lambda old: old() & ~need_evict)
+        in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
+        last = _set_lane(last, x, lambda _: t)
         count = count + insert.astype(jnp.int32) - need_evict.astype(jnp.int32)
         return dict(in_cache=in_cache, count=count, last=last, t=t + 1), hit
 
@@ -341,7 +393,7 @@ def step(
         # list operation is a masked write on the (lst, stamp) pair; list
         # sizes are mask sums, LRUs are masked stamp argmins.
         lst, stamp, p, t = state["lst"], state["stamp"], state["p"], state["t"]
-        lx = lst[x]
+        lx = _lane(lst, x)
         hit = (lx == 1) | (lx == 2)
         g1 = lx == 3
         g2 = lx == 4
@@ -372,8 +424,8 @@ def step(
         gone_b2 = cold & (~caseA) & (total >= 2 * cap) & (b2n > 0)
         b1_lru = _masked_argmin(stamp, lst == 3)
         b2_lru = _masked_argmin(stamp, lst == 4)
-        lst = lst.at[b1_lru].set(jnp.where(gone_b1, 0, lst[b1_lru]))
-        lst = lst.at[b2_lru].set(jnp.where(gone_b2, 0, lst[b2_lru]))
+        lst = _set_lane(lst, b1_lru, lambda old: jnp.where(gone_b1, 0, old()))
+        lst = _set_lane(lst, b2_lru, lambda old: jnp.where(gone_b2, 0, old()))
         # REPLACE: a filled miss about to insert into a full cache demotes
         # the LRU of T1 (when |T1| > p, or == p on a B2 hit, or T2 is empty)
         # to B1's MRU, else T2's LRU to B2's MRU. Flat ARC is provably full
@@ -386,8 +438,8 @@ def step(
         victim = jnp.where(hard_t1 | from_t1, t1_lru, t2_lru)
         evict = need_evict | hard_t1
         vdst = jnp.where(hard_t1, 0, jnp.where(from_t1, 3, 4))
-        lst = lst.at[victim].set(jnp.where(evict, vdst, lst[victim]))
-        stamp = stamp.at[victim].set(jnp.where(need_evict, t, stamp[victim]))
+        lst = _set_lane(lst, victim, lambda old: jnp.where(evict, vdst, old()))
+        stamp = _set_lane(stamp, victim, lambda old: jnp.where(need_evict, t, old()))
         # x's destination: any hit and every filled ghost hit land at T2's
         # MRU, a filled cold miss at T1's MRU; an unfilled ghost hit refreshes
         # in place (parked demand) and an unfilled cold miss parks in B1
@@ -397,8 +449,8 @@ def step(
             jnp.where(cold & fill, 1, jnp.where(ghost, lx, 3)),
         )
         write_x = ~park_skip
-        lst = lst.at[x].set(jnp.where(write_x, dst, lst[x]))
-        stamp = stamp.at[x].set(jnp.where(write_x, t, stamp[x]))
+        lst = _set_lane(lst, x, lambda old: jnp.where(write_x, dst, old()))
+        stamp = _set_lane(stamp, x, lambda old: jnp.where(write_x, t, old()))
         in_cache = (lst == 1) | (lst == 2)
         count = in_cache.sum().astype(jnp.int32)
         return dict(
@@ -429,7 +481,7 @@ def step(
         if spec.doorkeeper:
             bloom = jnp.where(age, jnp.zeros_like(bloom), bloom)
 
-        hit = in_cache[x]
+        hit = _lane(in_cache, x)
         if spec.capacity_bytes:
             # byte mode: "full" means the object does not fit as-is; a full
             # duel win frees room via the bounded loop (empty cache = no
@@ -453,10 +505,10 @@ def step(
                 spec, freq, in_cache, count, state["bytes"], size_x, want, cap_b, sizes
             )
             insert = want & (nbytes + size_x <= cap_b)
-            freq = freq.at[x].set(
-                jnp.where(hit, freq[x] + 1, jnp.where(insert, 1, freq[x]))
+            freq = _set_lane(
+                freq, x, lambda old: jnp.where(hit, old() + 1, jnp.where(insert, 1, old()))
             )
-            in_cache = in_cache.at[x].set(in_cache[x] | insert)
+            in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
             count = count + insert.astype(jnp.int32)
             nbytes = nbytes + jnp.where(insert, size_x, 0)
             out = dict(
@@ -468,13 +520,13 @@ def step(
             return out, hit
         insert = (~hit) & ((~full) | admit) & fill
         need_evict = (~hit) & full & admit & fill
-        in_cache = in_cache.at[victim].set(in_cache[victim] & ~need_evict)
+        in_cache = _set_lane(in_cache, victim, lambda old: old() & ~need_evict)
         # LFU eviction semantics: metadata dies with the victim, entry restarts at 1
-        freq = freq.at[victim].set(jnp.where(need_evict, 0, freq[victim]))
-        freq = freq.at[x].set(
-            jnp.where(hit, freq[x] + 1, jnp.where(insert, 1, freq[x]))
+        freq = _set_lane(freq, victim, lambda old: jnp.where(need_evict, 0, old()))
+        freq = _set_lane(
+            freq, x, lambda old: jnp.where(hit, old() + 1, jnp.where(insert, 1, old()))
         )
-        in_cache = in_cache.at[x].set(in_cache[x] | insert)
+        in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
         count = count + insert.astype(jnp.int32) - need_evict.astype(jnp.int32)
         inserts = state["inserts"] + insert.astype(jnp.int32)
         out = dict(
@@ -487,7 +539,10 @@ def step(
 
     # frequency family: lfu / plfu / plfua / plfua_dyn / gdsf
     freq = state["freq"]
-    hit = in_cache[x]
+    if spec.kind == "plfua":
+        hit, admitted = _lane((in_cache, state["hot"]), x)
+    else:
+        hit = _lane(in_cache, x)
     if spec.kind == "plfua_dyn":
         # the step only feeds the sketch; hot-set recomputation is *global-time*
         # and lives at the chunk boundaries of _chunked_scan / refresh_hot, so
@@ -498,10 +553,8 @@ def step(
         )
         # dynamic hot gates admission only: a cached object keeps hitting (and
         # bumping) after it leaves the hot set, until PLFU eviction removes it
-        admitted = state["hot"][x] | hit
-    elif spec.kind == "plfua":
-        admitted = state["hot"][x]
-    else:
+        admitted = _lane(state["hot"], x) | hit
+    elif spec.kind != "plfua":
         admitted = jnp.bool_(True)
     want = (~hit) & admitted & fill
     # an unfilled admitted miss still bumps the parked frequency (demand
@@ -530,23 +583,26 @@ def step(
         victim = _masked_argmin(key, in_cache)
         if spec.kind == "gdsf":
             # the aging credit ratchets to the evicted victim's priority
-            L = jnp.where(need_evict, score[victim], L)
-        in_cache = in_cache.at[victim].set(in_cache[victim] & ~need_evict)
+            L = jnp.where(need_evict, _lane(score, victim), L)
+        in_cache = _set_lane(in_cache, victim, lambda old: old() & ~need_evict)
         if spec.kind == "lfu":
             # in-memory LFU: eviction destroys the metadata -> restart from 1
-            freq = freq.at[victim].set(jnp.where(need_evict, 0, freq[victim]))
+            freq = _set_lane(freq, victim, lambda old: jnp.where(need_evict, 0, old()))
         insert = want
         count = count + insert.astype(jnp.int32) - need_evict.astype(jnp.int32)
     # PLFU/PLFUA/GDSF: freq[x] of a non-cached object *is* the parked-list
     # entry, so `freq[x] + 1` resumes from it; for LFU eviction zeroed it.
-    freq = freq.at[x].set(jnp.where(touch, freq[x] + 1, freq[x]))
+    freq = _set_lane(freq, x, lambda old: jnp.where(touch, old() + 1, old()))
     if spec.kind == "gdsf":
         # re-price under the post-eviction L; a merely-parked touch writes a
         # score the next insert overwrites, so cached lanes never see it
-        score = score.at[x].set(
-            jnp.where(touch, L + ((freq[x] << GDSF_SHIFT) // _sz(sizes, x)), score[x])
+        score = _set_lane(
+            score, x,
+            lambda old: jnp.where(
+                touch, L + ((_lane(freq, x) << GDSF_SHIFT) // _sz(sizes, x)), old()
+            ),
         )
-    in_cache = in_cache.at[x].set(in_cache[x] | insert)
+    in_cache = _set_lane(in_cache, x, lambda old: old() | insert)
     out = dict(in_cache=in_cache, count=count, freq=freq)
     if spec.kind == "gdsf":
         out.update(score=score, L=L)

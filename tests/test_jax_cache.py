@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import fleet, workloads
 from repro.core import jax_cache, policies, zipf
+from repro.fleet.reference import build_policy
 
 
 def _py_policy(kind, n, cap, window):
@@ -205,3 +207,88 @@ def test_plfua_prefix_scan_engages(kind, hot, compact):
         assert rows == {(S, h1)}
     else:
         assert (S, n) in rows and (S, h1) not in rows
+
+
+# Lane access (`jax_cache._lane` / `_set_lane`): rows of at most
+# `_ONE_HOT_LANES` lanes are read and written by a one-hot mask, longer ones
+# by index. Every kind, in byte mode too where it has one, runs a Zipf trace
+# on a row at the limit and on one lane past it, in the form its length
+# picks and, with the limit moved, in the other: both must agree field for
+# field with each other and hit for hit with the plain reference.
+LANE_CASES = [(kind, False) for kind in jax_cache.JAX_POLICY_KINDS] + [
+    (kind, True) for kind in jax_cache.JAX_POLICY_KINDS if kind != "arc"
+]
+
+
+def _lane_run(spec, trace, sizes):
+    """One run traced afresh (the lane form is chosen at trace time).
+    Static plfua scans its whole row: `simulate` would scan the admissible
+    prefix alone."""
+    jax.clear_caches()
+    if spec.kind == "plfua":
+        return jax.jit(functools.partial(_dense_simulate, spec))(trace, sizes)
+    return jax_cache.simulate(spec, trace, None, sizes)
+
+
+@pytest.mark.parametrize("past", [False, True], ids=["at_limit", "past_limit"])
+@pytest.mark.parametrize("kind,sized", LANE_CASES)
+def test_lane_forms_agree_with_reference(kind, sized, past, monkeypatch):
+    limit = jax_cache._ONE_HOT_LANES
+    n = limit + 1 if past else limit
+    spec = jax_cache.PolicySpec(
+        kind, n_objects=n, capacity=24, hot_size=48,
+        window=48 if kind in ("wlfu", "tinylfu") else 0,
+        refresh=97 if kind == "plfua_dyn" else 0,
+        sketch_width=64 if kind in jax_cache.SKETCH_POLICY_KINDS else 0,
+        capacity_bytes=200 if sized else 0,
+    )
+    sizes = (
+        workloads.object_sizes(n, corr=0.5, seed=3, median=8, max_size=64)
+        if sized or kind == "gdsf" else None
+    )
+    trace = zipf.sample_trace(n, 400, seed=13)
+    hits, state = _lane_run(spec, trace, sizes)
+    monkeypatch.setattr(jax_cache, "_ONE_HOT_LANES", n if past else n - 1)
+    other_hits, other_state = _lane_run(spec, trace, sizes)
+    np.testing.assert_array_equal(np.asarray(hits), np.asarray(other_hits))
+    assert sorted(state) == sorted(other_state)
+    for key in state:
+        np.testing.assert_array_equal(
+            np.asarray(state[key]), np.asarray(other_state[key]), err_msg=key
+        )
+
+    pol = build_policy(spec, sizes)
+    ref_hits = np.array([pol.request(int(x)) for x in trace])
+    hits = np.asarray(hits)
+    np.testing.assert_array_equal(hits, ref_hits)
+    cached = np.asarray(state["in_cache"])
+    np.testing.assert_array_equal(
+        np.flatnonzero(cached), [x for x in np.unique(trace) if pol.contains(int(x))]
+    )
+    assert int(state["count"]) == int(cached.sum())
+    assert jax_cache.eviction_count(spec, hits, trace, state) == pol.evictions
+    assert int(jax_cache.metadata_entries(spec, state)) == pol.metadata_entries
+
+
+def test_fast_path_lanes_at_cell_shape_match_simulate():
+    """The flat stream's compact fast path at the shape of the paper's
+    largest grid case (N 100,000, C 2,000, hot set 4,000, chunks of 2,048:
+    rows of 6,096 lanes, the one-hot form) against `simulate` over the
+    concatenated chunks."""
+    n, cap, hot, g, chunks = 100_000, 2_000, 4_000, 2_048, 3
+    spec = jax_cache.PolicySpec("plfua", n_objects=n, capacity=cap, hot_size=hot)
+    topo = fleet.tree(n_objects=n, widths=(1,), kinds="plfua", capacities=cap,
+                      hot_size=(hot,))
+    fs = fleet.FleetStream(fleet.StreamConfig(topo=topo, chunk_len=g, fast=True))
+    assert fs._lanes == 6_096 <= jax_cache._ONE_HOT_LANES
+    trace = zipf.sample_trace(n, chunks * g, seed=21).astype(np.int32)
+    hits = [np.asarray(fs.push(jnp.asarray(trace[c * g:(c + 1) * g]))["hit"][0])
+            for c in range(chunks)]
+    want_hits, want_state = jax_cache.simulate(spec, trace)
+    np.testing.assert_array_equal(np.concatenate(hits), np.asarray(want_hits))
+    state = fs.states()[0]
+    assert sorted(state) == sorted(want_state)
+    for key in state:
+        np.testing.assert_array_equal(
+            np.asarray(state[key]), np.asarray(want_state[key]), err_msg=key
+        )

@@ -6,6 +6,7 @@ The scopes must change nothing but operation metadata: each program's
 optimized HLO is compared with a twin built with ``jax.named_scope`` patched
 to a null context, metadata and stack-frame tables stripped."""
 import contextlib
+import functools
 import re
 
 import jax
@@ -160,3 +161,107 @@ def test_lanes_are_none_off_the_fast_path():
         if stepped == 2:
             assert st.node_steps == st.lanes
 
+
+
+# Lane access by shape (`jax_cache._ONE_HOT_LANES`): a step on short rows
+# reads and writes a lane by a one-hot mask, under `repro.onehot`, and its
+# loop body addresses no row by a computed index; a step on long rows keeps
+# the index form and no `repro.onehot`.
+_INDEXED = {"gather", "scatter", "scatter-add", "dynamic_slice", "dynamic_update_slice"}
+
+
+def _primitives(jaxpr) -> set:
+    """Names of the primitives of a jaxpr, sub-jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def _step_loop_bodies(jaxpr) -> list:
+    """The bodies of the per-request scans: those under `repro.step`."""
+    bodies = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and "repro.step" in str(eqn.source_info.name_stack):
+            bodies.append(eqn.params["jaxpr"].jaxpr)
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    bodies += _step_loop_bodies(sub)
+    return bodies
+
+
+def _cell_program(name):
+    """(program, abstract args) of the two plfua cells at their shapes: the
+    flat stream's fast chunk (rows of 6,096 compact lanes) and 12
+    replications (rows of the 4,001-lane admissible prefix)."""
+    n, cap, hot, g = 100_000, 2_000, 4_000, 2_048
+    if name == "replications":
+        spec = PolicySpec("plfua", n, cap, hot_size=hot)
+        return (functools.partial(jax_cache.simulate_batch, spec),
+                (jax.ShapeDtypeStruct((12, n), jnp.int32),))
+    topo = fleet.tree(n_objects=n, widths=(1,), kinds="plfua", capacities=cap,
+                      hot_size=(hot,))
+    fs = FleetStream(StreamConfig(topo=topo, chunk_len=g, fast=True))
+    return fs._push_fn, (fs._carry, jax.ShapeDtypeStruct((g,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", ["stream", "replications"])
+def test_cells_step_loops_use_one_hot_lanes(name):
+    fn, args = _cell_program(name)
+    assert "repro.onehot" in jax.jit(fn).lower(*args).as_text(debug_info=True)
+    bodies = _step_loop_bodies(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(bodies) == 1
+    assert not _primitives(bodies[0]) & _INDEXED, sorted(_primitives(bodies[0]) & _INDEXED)
+
+
+_SHORT_KINDS = [(k, False) for k in ("lru", "lfu", "plfu", "plfua", "wlfu", "gdsf", "arc")] + [
+    (k, True) for k in ("lru", "lfu", "plfu", "plfua", "wlfu", "gdsf")
+]
+
+
+@pytest.mark.parametrize("batch", [None, 12])
+@pytest.mark.parametrize("kind,sized", _SHORT_KINDS)
+def test_short_row_step_has_no_indexed_access(kind, sized, batch):
+    """Every kind without a sketch table (tinylfu and plfua_dyn gather their
+    per-object hash rows, which this does not change), in byte mode too."""
+    spec = PolicySpec(kind, N, C, hot_size=2 * C, window=8 if kind == "wlfu" else 0,
+                      capacity_bytes=4 * C if sized else 0)
+    assert N <= jax_cache._ONE_HOT_LANES
+    sizes = jnp.ones((N,), jnp.int32) if spec.size_aware else None
+
+    def one(s, x):
+        return jax_cache.step(spec, s, x, sizes=sizes)
+
+    fn = one if batch is None else jax.vmap(one)
+    state = jax_cache.init_state(spec)
+    x = jnp.int32(3)
+    if batch is not None:
+        state = jax.tree_util.tree_map(lambda a: jnp.broadcast_to(a, (batch,) + a.shape), state)
+        x = jnp.full((batch,), 3, jnp.int32)
+    assert not _primitives(jax.make_jaxpr(fn)(state, x).jaxpr) & _INDEXED
+    assert "repro.onehot" in jax.jit(fn).lower(state, x).as_text(debug_info=True)
+
+
+def test_long_rows_keep_the_index_form():
+    """A step on 100,000-lane rows, alone and as the photo-CDN fleet's
+    level-major chunk program (8 plfua edges over 4 plfu origin nodes)."""
+    spec = PolicySpec("plfua", 100_000, 2_000, hot_size=4_000)
+    one = functools.partial(jax_cache.step, spec)
+    state, x = jax_cache.init_state(spec), jnp.int32(3)
+    assert "repro.onehot" not in jax.jit(one).lower(state, x).as_text(debug_info=True)
+    assert _primitives(jax.make_jaxpr(one)(state, x).jaxpr) & _INDEXED
+    topo = fleet.tree(n_objects=100_000, widths=(8, 4), kinds=("plfua", "plfu"),
+                      capacities=(2_000, 5_000), hot_size=(4_000, 0),
+                      routers=("sticky", "hash"), session_len=64)
+    fs = FleetStream(StreamConfig(topo=topo, chunk_len=2_048))
+    chunk = jax.ShapeDtypeStruct((2_048,), jnp.int32)
+    lowered = fs._push_fn.lower(fs._carry, chunk, chunk)
+    assert "repro.onehot" not in lowered.as_text(debug_info=True)
