@@ -399,18 +399,20 @@ def step(
         g2 = lx == 4
         ghost = g1 | g2
         cold = lx == 0
-        t1n = (lst == 1).sum().astype(jnp.int32)
-        t2n = (lst == 2).sum().astype(jnp.int32)
-        b1n = (lst == 3).sum().astype(jnp.int32)
-        b2n = (lst == 4).sum().astype(jnp.int32)
-        total = t1n + t2n + b1n + b2n
-        # adaptation (ghost hits only, filled or not): a B1 hit grows the
-        # recency target p, a B2 hit shrinks it — integer deltas
-        d1 = jnp.maximum(1, b2n // jnp.maximum(1, b1n))
-        d2 = jnp.maximum(1, b1n // jnp.maximum(1, b2n))
-        p = jnp.where(
-            g1, jnp.minimum(cap, p + d1), jnp.where(g2, jnp.maximum(0, p - d2), p)
-        )
+        # the list sizes and the adaptation of p: profile scope repro.lists
+        with jax.named_scope("repro.lists"):
+            t1n = (lst == 1).sum().astype(jnp.int32)
+            t2n = (lst == 2).sum().astype(jnp.int32)
+            b1n = (lst == 3).sum().astype(jnp.int32)
+            b2n = (lst == 4).sum().astype(jnp.int32)
+            total = t1n + t2n + b1n + b2n
+            # adaptation (ghost hits only, filled or not): a B1 hit grows the
+            # recency target p, a B2 hit shrinks it — integer deltas
+            d1 = jnp.maximum(1, b2n // jnp.maximum(1, b1n))
+            d2 = jnp.maximum(1, b1n // jnp.maximum(1, b2n))
+            p = jnp.where(
+                g1, jnp.minimum(cap, p + d1), jnp.where(g2, jnp.maximum(0, p - d2), p)
+            )
         # Case IV ghost trimming (cold misses only). Filled: IV(a) drops the
         # LRU of B1 when the recency side T1+B1 is at capacity (B1 empty ->
         # hard-drop T1's LRU instead, no ghost left behind), IV(b) drops the

@@ -36,17 +36,31 @@ line rate. This module runs that stream as a sequence of
 
 The **fast path** (``StreamConfig(fast=True)``, single flat cache) replaces
 the dense (n_objects,)-per-step scan with a compact working-set engine: per
-chunk it selects the ``P = min(2*chunk_len, capacity + chunk_len)``
-lexicographically smallest ``(eviction_key, id)`` cached candidates from a
-sorted roster, unions them with the chunk's ids, and runs the unchanged
-``jax_cache.step`` on the ``P + chunk_len`` compact lanes (sentinel-padded,
-scattered back with ``mode="drop"``). Correctness rests on the candidate-
-prefix bound: one step invalidates at most two prefix entries (the touched
-object and the evicted victim; every other cached object's eviction key is
-constant within a chunk for the FAST_KINDS), so a ``2*chunk_len`` prefix
-always contains the true victim, ties included — the compact lanes are
-id-sorted, making the masked argmin's tie-break identical to the dense
-engine's lowest-id rule. Pinned bit-exact against ``jax_cache.simulate`` in
+chunk it unions a candidate set drawn from a sorted roster with the chunk's
+ids, and runs the unchanged ``jax_cache.step`` on those ``M`` compact lanes
+(id-sorted, sentinel-padded, scattered back with ``mode="drop"``). The
+roster and the candidates depend on the kind, decided statically:
+
+* **Residents** (every FAST_KIND but arc): the roster holds the cached ids
+  (``capacity + chunk_len`` slots) and the candidates are the ``P =
+  min(2*chunk_len, capacity + chunk_len)`` lexicographically smallest
+  ``(eviction_key, id)`` of them. Correctness rests on the candidate-prefix
+  bound: one step invalidates at most two prefix entries (the touched
+  object and the evicted victim; every other cached object's eviction key
+  is constant within a chunk for these kinds), so a ``2*chunk_len`` prefix
+  always contains the true victim, ties included — the compact lanes are
+  id-sorted, making the masked argmin's tie-break identical to the dense
+  engine's lowest-id rule.
+* **Directory** (arc): ARC's step reads and moves ghosts (a ghost hit adapts
+  ``p``, a cold miss trims the LRU of B1 or B2), so the roster holds its
+  whole directory, every id on one of the four lists (``lst != 0``), and
+  the candidates are the whole roster. The directory bound |T1| + |T2| +
+  |B1| + |B2| <= 2c (tests/test_arc.py) sizes the roster at exactly
+  ``2 * capacity`` slots, and every id the step can read or move is then a
+  lane: no candidate argument is needed. Lanes that hold no real object
+  read unlisted (``lst = 0``), so they never enter a list's size.
+
+Both are pinned bit-exact against ``jax_cache.simulate`` in
 tests/test_stream.py.
 """
 from __future__ import annotations
@@ -73,12 +87,14 @@ __all__ = [
     "stream_fleet",
 ]
 
-#: kinds whose eviction key is per-object and touch-local (untouched cached
-#: objects keep their key within a chunk), which is what the fast path's
-#: candidate-prefix bound needs. wlfu is out (the ring slide retires other
-#: objects' window counts every step), arc is out (REPLACE moves whole-list
-#: LRU positions), byte mode is out (one insert can evict many victims).
-FAST_KINDS = ("lru", "lfu", "plfu", "plfua", "plfua_dyn", "gdsf", "tinylfu")
+#: kinds the fast path takes. All but arc have an eviction key that is
+#: per-object and touch-local (untouched cached objects keep their key
+#: within a chunk), which is what the candidate-prefix bound needs; arc
+#: (whose REPLACE moves whole-list LRU positions) instead steps its whole
+#: directory, bounded by 2c. wlfu is out (the ring slide retires other
+#: objects' window counts every step), byte mode is out (one insert can
+#: evict many victims).
+FAST_KINDS = ("lru", "lfu", "plfu", "plfua", "plfua_dyn", "gdsf", "tinylfu", "arc")
 
 @dataclasses.dataclass(frozen=True)
 class StreamConfig:
@@ -153,7 +169,10 @@ class StreamStats:
     ``lanes_valid / node_steps`` is the share of stepped work that served a
     request. Placed engine: one gathered node-step per level and position,
     chunks x ``chunk_len`` x levels, all stepped, and the same active
-    count."""
+    count. ``ghost_lanes`` counts the fast path's lanes that hold an ARC
+    ghost (B1 or B2) at each chunk's start, summed over chunks (an int32
+    device counter): 0 for the other fast kinds, ``None`` off the fast
+    path, where no engine counts it."""
 
     requests: int
     chunks: int
@@ -167,6 +186,7 @@ class StreamStats:
     lanes: int | None = None
     lanes_valid: int | None = None
     node_steps: int | None = None
+    ghost_lanes: int | None = None
 
     @property
     def total_chr(self) -> float:
@@ -191,16 +211,22 @@ class StreamStats:
 
 # --------------------------------------------------- fast compact-lane path
 #: per-object state fields gathered into compact lanes (everything else in
-#: a FAST_KINDS state — count/t/L/sketch/seen/inserts/bloom — is a scalar or
-#: a small table that passes through unchanged)
-_PER_OBJECT_FIELDS = ("last", "freq", "score", "hot")
+#: a FAST_KINDS state — count/t/p/L/sketch/seen/inserts/bloom — is a scalar
+#: or a small table that passes through unchanged)
+_PER_OBJECT_FIELDS = ("last", "freq", "score", "hot", "lst", "stamp")
 
 
 def _build_fast(cfg: StreamConfig, sizes):
     spec = cfg.topo.levels[0][0]
     N, G = spec.n_objects, cfg.chunk_len
-    R = spec.capacity + G  # roster slots: residents never exceed cap (+G slack)
-    P = min(2 * G, R)  # candidate prefix (>= 2 invalidations/step bound)
+    directory = spec.kind == "arc"
+    if directory:
+        # ARC's whole directory: |T1| + |T2| + |B1| + |B2| <= 2c, every
+        # entry a candidate
+        R = P = 2 * spec.capacity
+    else:
+        R = spec.capacity + G  # roster slots: residents never exceed cap (+G slack)
+        P = min(2 * G, R)  # candidate prefix (>= 2 invalidations/step bound)
     M = P + G
     cspec = dataclasses.replace(spec, n_objects=M)
     sketchy = spec.kind in jax_cache.SKETCH_POLICY_KINDS
@@ -210,14 +236,18 @@ def _build_fast(cfg: StreamConfig, sizes):
     def fast_chunk(carry, trace):
         state, roster, t0 = carry["state"], carry["roster"], carry["t0"]
         xs = trace.astype(jnp.int32)
-        # ---- candidates: the P lex-smallest (eviction_key, id) cached pairs,
-        # selected over the roster (every resident), sentinel-padded with N
-        with jax.named_scope("repro.select"):
-            key = sim_mod._victim_key(spec, state)
-            rc = jnp.minimum(roster, N - 1)
-            rkey = jnp.where(roster < N, key[rc], jax_cache._I32_MAX)
-            _, sid = jax.lax.sort((rkey, roster), num_keys=2)
-            cand = jax.lax.slice_in_dim(sid, 0, P)
+        if directory:
+            cand = roster
+        else:
+            # ---- candidates: the P lex-smallest (eviction_key, id) cached
+            # pairs, selected over the roster (every resident),
+            # sentinel-padded with N
+            with jax.named_scope("repro.select"):
+                key = sim_mod._victim_key(spec, state)
+                rc = jnp.minimum(roster, N - 1)
+                rkey = jnp.where(roster < N, key[rc], jax_cache._I32_MAX)
+                _, sid = jax.lax.sort((rkey, roster), num_keys=2)
+                cand = jax.lax.slice_in_dim(sid, 0, P)
         # ---- lanes: candidates ∪ chunk ids, id-sorted, deduped to sentinel
         with jax.named_scope("repro.lanes"):
             ids = jnp.sort(jnp.concatenate([cand, xs]))
@@ -230,6 +260,10 @@ def _build_fast(cfg: StreamConfig, sizes):
                 if k == "in_cache":
                     # invalid lanes must read not-cached (they hold garbage rows)
                     cstate[k] = valid & v[idc]
+                elif k == "lst":
+                    # and unlisted: a sentinel lane reads id N - 1's row,
+                    # which may be on a list, and would count in its size
+                    cstate[k] = jnp.where(valid, v[idc], 0)
                 elif k in _PER_OBJECT_FIELDS:
                     cstate[k] = v[idc]
                 else:
@@ -238,6 +272,8 @@ def _build_fast(cfg: StreamConfig, sizes):
             bloom_c = None if big_bloom is None else jnp.asarray(big_bloom)[idc]
             sizes_c = None if sizes is None else sizes[idc]
             lx = jnp.searchsorted(ids, xs).astype(jnp.int32)
+        if directory:
+            ghosts = (cstate["lst"] >= 3).sum(dtype=jnp.int32)  # B1, B2
 
         def f(cs, xl):
             return jax_cache.step(
@@ -264,11 +300,12 @@ def _build_fast(cfg: StreamConfig, sizes):
                 lambda s: s,
                 new_state,
             )
-        # ---- roster rebuild: residents ⊆ old roster ∪ chunk ids
+        # ---- roster rebuild: residents (the directory) ⊆ old roster ∪ chunk ids
         with jax.named_scope("repro.roster"):
             r2 = jnp.sort(jnp.concatenate([roster, xs]))
             dup2 = jnp.concatenate([jnp.zeros((1,), jnp.bool_), r2[1:] == r2[:-1]])
-            keep = (~dup2) & (r2 < N) & new_state["in_cache"][jnp.minimum(r2, N - 1)]
+            listed = new_state["lst"] != 0 if directory else new_state["in_cache"]
+            keep = (~dup2) & (r2 < N) & listed[jnp.minimum(r2, N - 1)]
             new_roster = jax.lax.slice_in_dim(jnp.sort(jnp.where(keep, r2, N)), 0, R)
         new_carry = {
             "state": new_state,
@@ -277,6 +314,8 @@ def _build_fast(cfg: StreamConfig, sizes):
             "lanes_valid": carry["lanes_valid"] + valid.sum(dtype=jnp.int32),
             "t0": t0 + jnp.int32(G),
         }
+        if directory:
+            new_carry["ghost_lanes"] = carry["ghost_lanes"] + ghosts
         return new_carry, {
             "hit": (hits,),
             "node_hit": (hits[None, :],),
@@ -290,6 +329,8 @@ def _build_fast(cfg: StreamConfig, sizes):
         "lanes_valid": jnp.zeros((), jnp.int32),
         "t0": jnp.zeros((), jnp.int32),
     }
+    if directory:
+        carry0["ghost_lanes"] = jnp.zeros((), jnp.int32)
     return jax.jit(fast_chunk, donate_argnums=0), carry0, M
 
 
@@ -423,6 +464,7 @@ class FleetStream:
                 lanes=self.chunks * self._lanes,
                 lanes_valid=int(carry["lanes_valid"]),
                 node_steps=self.chunks * self._lanes,
+                ghost_lanes=int(carry.get("ghost_lanes", 0)),
             )
         carry = self._carry
         origin = int(carry["origin"])

@@ -30,6 +30,25 @@ def _fast(kind="plfua_dyn"):
     return FleetStream(StreamConfig(topo=topo, chunk_len=G, fast=True))
 
 
+def _fast_arc():
+    topo = fleet.tree(n_objects=N, widths=(1,), kinds="arc", capacities=C)
+    return FleetStream(StreamConfig(topo=topo, chunk_len=G, fast=True))
+
+
+def test_arc_fast_chunk_carries_list_scopes():
+    """ARC's fast chunk steps its directory in one-hot lanes, its list sizes
+    and adaptation under ``repro.lists``; plfua's has no ``repro.lists``."""
+    fs = _fast_arc()
+    text = fs._push_fn.lower(fs._carry, _CHUNK).as_text(debug_info=True)
+    found = set(re.findall(r"\brepro\.([A-Za-z]\w*)", text))
+    assert {"lists", "onehot", "victim", "step", "lanes", "scatter", "roster"} <= found
+    assert "select" not in found  # the whole directory is the candidate set
+    plfua = _fast("plfua")
+    assert "repro.lists" not in plfua._push_fn.lower(plfua._carry, _CHUNK).as_text(
+        debug_info=True
+    )
+
+
 def _level_major():
     topo = fleet.tree(n_objects=N, widths=(2, 1), kinds=("lru", "plfua_dyn"),
                       capacities=(C, 2 * C), refresh=(0, 2 * G), router="hash")

@@ -18,7 +18,8 @@ same algorithms* the paper tables score, so this suite pins it over:
   chunk — including a chunk length that does *not* divide the refresh
   period (gcd sub-chunking);
 * the fast compact-lane path against the dense flat simulator for every
-  FAST_KIND (the candidate-prefix bound, tie-breaks included);
+  FAST_KIND (the candidate-prefix bound, tie-breaks included; arc's whole
+  directory as lanes, filled to its 2c bound);
 * the double-buffered ``stream_fleet`` driver against a bounded run over
   the same on-device-generated chunks;
 * position-keyed routers (``sticky``, ``round_robin``) at every level,
@@ -198,9 +199,9 @@ def test_stream_config_validation():
     # fast-path preconditions
     with pytest.raises(ValueError, match="depth-1"):
         StreamConfig(topo=topo, chunk_len=G, fast=True)
-    flat_arc = fleet.tree(n_objects=N, widths=(1,), kinds="arc", capacities=13)
+    flat_wlfu = fleet.tree(n_objects=N, widths=(1,), kinds="wlfu", capacities=13, window=48)
     with pytest.raises(ValueError, match="fast=True supports"):
-        StreamConfig(topo=flat_arc, chunk_len=G, fast=True)
+        StreamConfig(topo=flat_wlfu, chunk_len=G, fast=True)
     flat = fleet.tree(n_objects=N, widths=(1,), kinds="lru", capacities=13)
     with pytest.raises(ValueError, match="telemetry"):
         StreamConfig(
@@ -311,6 +312,7 @@ def test_stream_placed_bit_identity(pl):
 _FAST_SPECS = {
     "lru": {}, "lfu": {}, "plfu": {"hot_size": 24}, "plfua": {"hot_size": 24},
     "plfua_dyn": {"hot_size": 24, "refresh": 2 * G}, "gdsf": {}, "tinylfu": {},
+    "arc": {},
 }
 
 
@@ -346,6 +348,48 @@ def test_stream_fast_parity(kind):
     assert st.hits == int(np.asarray(ref_hits).sum())
     assert st.requests == T
     assert int(st.tiers[0]["count"][0]) == int(ref_state["count"])
+
+
+def test_stream_fast_arc_full_directory():
+    """ARC's directory roster at its bound: a churn stream long enough that
+    the directory holds exactly 2c entries, id N - 1 among them (a sentinel
+    lane reads that id's row, so it must read unlisted), equals the dense
+    simulator field for field; ``ghost_lanes`` counts the B1/B2 lanes of
+    every chunk's start, recounted from the states."""
+    n, cap, g, k = 300, 13, 50, 40
+    spec = PolicySpec(kind="arc", n_objects=n, capacity=cap)
+    trace = workloads.make_traces("churn", n, 1, g * k, seed=7)[0].astype(np.int32)
+    trace[::7] = n - 1
+    ref_hits, ref_state = jax_cache.simulate(spec, jnp.asarray(trace))
+    topo = fleet.tree(n_objects=n, widths=(1,), kinds="arc", capacities=cap)
+    fs = FleetStream(StreamConfig(topo=topo, chunk_len=g, fast=True))
+    hits, ghosts = [], 0
+    for c in range(k):
+        ghosts += int((np.asarray(fs.states()[0]["lst"]) >= 3).sum())
+        hits.append(np.asarray(fs.push(jnp.asarray(trace[c * g:(c + 1) * g]))["hit"][0]))
+    np.testing.assert_array_equal(np.concatenate(hits), np.asarray(ref_hits))
+    fstate = fs.states()[0]
+    for key in ref_state:
+        np.testing.assert_array_equal(
+            np.asarray(ref_state[key]), np.asarray(fstate[key]), err_msg=f"state[{key}]"
+        )
+    lst = np.asarray(fstate["lst"])
+    assert (lst != 0).sum() == 2 * cap and lst[n - 1] != 0
+    st = fs.stats()
+    assert st.ghost_lanes == ghosts > 0
+    assert st.lanes == k * (2 * cap + g) and 0 < st.lanes_valid < st.lanes
+
+
+def test_stream_ghost_lanes_only_where_counted():
+    """Other fast kinds hold no ghosts (0); the level-major engine counts none."""
+    trace = workloads.make_traces("stationary", N, 1, G, seed=3)[0]
+    flat = fleet.tree(n_objects=N, widths=(1,), kinds="lru", capacities=13)
+    fast = FleetStream(StreamConfig(topo=flat, chunk_len=G, fast=True))
+    fast.push(jnp.asarray(trace))
+    assert fast.stats().ghost_lanes == 0
+    dense = FleetStream(StreamConfig(topo=_topo("arc"), chunk_len=G))
+    dense.push(jnp.asarray(trace), jnp.asarray(_topo("arc").assignment(trace)))
+    assert dense.stats().ghost_lanes is None
 
 
 def test_stream_fast_sized_gdsf():
